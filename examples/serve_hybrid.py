@@ -3,8 +3,8 @@ queries in flight at once.
 
 Quickstart
 ----------
-Two serving engines (a small 'edge' model and a larger 'cloud' model,
-both reduced variants of assigned architectures) execute subtasks
+Two serving engines (an 'edge' model and a 'cloud' model, built by the
+serving launcher's ``build_engines``) execute subtasks
 scheduled by the dependency-aware router. The multi-query runtime admits
 every query up front; dispatch goes through the fleet scheduler's *async
 pump loop*: every routed subtask is ``submit``-ed into its engine's
@@ -16,21 +16,26 @@ slot pool, and prompts longer than ``prefill_chunk`` advance one chunk
 per step so they never stall co-resident decodes. Sampling happens on
 device inside the jitted step (one host transfer of token ids per step).
 
-    # pumped fleet serving (default: 8 queries in flight)
-    PYTHONPATH=src python examples/serve_hybrid.py --queries 8
+    # pumped fleet serving (default: 8 queries in flight) at the CPU
+    # smoke size; without --reduced both engines run at published widths
+    PYTHONPATH=src python examples/serve_hybrid.py --reduced --queries 8
 
     # pre-pump synchronous dispatch (engines never co-batch queries)
-    PYTHONPATH=src python examples/serve_hybrid.py --queries 8 --no-pump
+    PYTHONPATH=src python examples/serve_hybrid.py --reduced --queries 8 \
+        --no-pump
 
     # compare against the seed's one-query-at-a-time loop
-    PYTHONPATH=src python examples/serve_hybrid.py --queries 8 --sequential
+    PYTHONPATH=src python examples/serve_hybrid.py --reduced --queries 8 \
+        --sequential
 
     # cap fleet-wide API spend; exhaustion forces edge execution
-    PYTHONPATH=src python examples/serve_hybrid.py --global-k-max 0.01
+    PYTHONPATH=src python examples/serve_hybrid.py --reduced \
+        --global-k-max 0.01
 
     # shard the cloud engine across 2 pool replicas (shared params,
     # independent KV slot pools; cloud concurrency = replicas x slots)
-    PYTHONPATH=src python examples/serve_hybrid.py --cloud-replicas 2
+    PYTHONPATH=src python examples/serve_hybrid.py --reduced \
+        --cloud-replicas 2
 
 The printed report includes fleet throughput, p50/p99 per-query
 makespan, accuracy and API cost, plus the engines' counters —
@@ -54,33 +59,20 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax
-import jax.numpy as jnp
-
-from repro.configs import get_config, PAPER_EDGE_ARCH, PAPER_CLOUD_ARCH
+from repro.configs import PAPER_EDGE_ARCH, PAPER_CLOUD_ARCH
 from repro.core.hybridflow import HybridFlowPolicy
 from repro.core.planner import SyntheticPlanner
 from repro.core.profiler import train_default_router
 from repro.data.tasks import gen_benchmark, WorldModel
-from repro.models import model as M
+from repro.launch.serve import build_engines
 from repro.serving import ServingConfig, ServingRuntime
-from repro.serving.engine import ServingEngine, JAXExecutor
-
-
-def build_engine(arch: str, scale: int, seed: int,
-                 batch_slots: int = 2,
-                 prefill_chunk: int = 64) -> ServingEngine:
-    cfg = get_config(arch).reduced()
-    if scale > 1:  # "cloud": wider/deeper variant
-        cfg = cfg.variant(d_model=cfg.d_model * 2 // 128 * 128 or 256,
-                          n_layers=2)
-    params = M.init_params(cfg, jax.random.PRNGKey(seed), dtype=jnp.float32)
-    return ServingEngine(cfg, params, batch_slots=batch_slots, max_len=192,
-                         prefill_chunk=prefill_chunk)
+from repro.serving.engine import JAXExecutor
 
 
 def main():
     ap = argparse.ArgumentParser()
+    ap.add_argument("--reduced", action="store_true",
+                    help="2-layer CPU smoke variant of each config")
     ap.add_argument("--queries", type=int, default=6)
     ap.add_argument("--edge-arch", default=PAPER_EDGE_ARCH)
     ap.add_argument("--cloud-arch", default=PAPER_CLOUD_ARCH)
@@ -94,11 +86,12 @@ def main():
                          "baseline)")
     args = ap.parse_args()
 
-    print(f"edge executor: {args.edge_arch} (reduced); "
-          f"cloud executor: {args.cloud_arch} (reduced x2)")
+    size = "reduced" if args.reduced else "published widths"
+    print(f"edge executor: {args.edge_arch}; cloud executor: "
+          f"{args.cloud_arch} ({size})")
     wm = WorldModel()
-    edge_engine = build_engine(args.edge_arch, 1, 0, batch_slots=2)
-    cloud_engine = build_engine(args.cloud_arch, 2, 1, batch_slots=4)
+    edge_engine, cloud_engine = build_engines(
+        args.edge_arch, args.cloud_arch, reduced=args.reduced)
     edge = JAXExecutor(edge_engine, wm, cloud=False, concurrency=1)
     # concurrency derives from capacity; --cloud-replicas scales this
     # executor out to an EnginePool inside the runtime

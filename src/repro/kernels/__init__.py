@@ -52,8 +52,9 @@ which route to the jit'd wrappers in ``ops.py`` when
 ``dispatch.use_pallas()`` is on (REPRO_USE_PALLAS=1 or
 ``pallas_enabled(True)``) and to the jnp reference otherwise. The
 wrappers own layout transposes ([B,S,H,hd] model layout -> [B,H,S,hd]
-blocked layout), GQA head mapping, padding to block multiples, and
-interpret-mode selection (CPU interprets; real TPUs compile). Decode
+blocked layout), GQA head mapping and padding to block multiples. Every
+kernel entry point takes ``interpret=None`` by default, which follows
+``dispatch.interpret_mode()``: CPU interprets, a TPU compiles. Decode
 sampling is fused at the XLA level: ``engine._jit_steps`` jits the
 attention output straight into ``_device_sample`` so the sampled token
 ids are the only per-tick host transfer.

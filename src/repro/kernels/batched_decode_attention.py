@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
+from repro.kernels.dispatch import resolve_interpret
 
 NEG_INF = -1e30
 _SUBLANE = 8   # pad the folded [KV, grp, hd] q tile up to 8 sublane rows
@@ -109,7 +109,7 @@ def _batched_decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def batched_decode_attention_bhmd(q, k, v, kv_len, *,
                                   window: Optional[int] = None,
-                                  bk: int = 256, interpret: bool = True):
+                                  bk: int = 256, interpret: Optional[bool] = None):
     """q [B,H,hd]; k/v [B,KV,M,hd]; kv_len [B] -> o [B,H,hd].
 
     ``bk`` is clamped to the cache width (non-multiple tails are padded
@@ -155,8 +155,8 @@ def batched_decode_attention_bhmd(q, k, v, kv_len, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, gp, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(kv_len, jnp.int32), qf, k, v)
     return out[:, :, :grp].reshape(B, H, hd)

@@ -15,13 +15,14 @@ validated against gla_reference in interpret mode.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
+from repro.kernels.dispatch import resolve_interpret
 
 
 # Ref order contract (checked statically by reprolint pallas-contract):
@@ -63,7 +64,7 @@ def _gla_kernel(q_ref, k_ref, v_ref, la_ref, o_ref, s_scr, *, chunk: int):
 
 
 def chunked_gla_bhtd(q, k, v, log_a, *, chunk: int = 128,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """q,k [B,H,T,Dk]; v [B,H,T,Dv]; log_a [B,H,T] -> y [B,H,T,Dv].
 
     T is padded to a chunk multiple with log_a=0, k=v=0 (identity steps).
@@ -91,8 +92,8 @@ def chunked_gla_bhtd(q, k, v, log_a, *, chunk: int = 128,
         out_specs=pl.BlockSpec((1, 1, C, Dv), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T + pt, Dv), v.dtype),
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v, log_a)
     return out[:, :, :T]

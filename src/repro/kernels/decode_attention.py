@@ -22,13 +22,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
+from repro.kernels.dispatch import resolve_interpret
 
 NEG_INF = -1e30
 _QROWS = 8  # sublane padding for the single query row
@@ -74,7 +75,7 @@ def _decode_kernel(kv_len_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def decode_attention_bhmd(q, k, v, kv_len, *, bk: int = 512,
-                          interpret: bool = True):
+                          interpret: Optional[bool] = None):
     """q [B,H,hd]; k/v [B,KV,M,hd]; kv_len [B] -> o [B,H,hd]."""
     B, H, hd = q.shape
     KV, M = k.shape[1], k.shape[2]
@@ -108,8 +109,8 @@ def decode_attention_bhmd(q, k, v, kv_len, *, bk: int = 512,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, _QROWS, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(kv_len, jnp.int32), qp, k, v)
     return out[:, :, 0]

@@ -2,15 +2,16 @@
 
 On TPU, enable with ``set_use_pallas(True)`` (or REPRO_USE_PALLAS=1). On
 CPU the kernels run in interpret mode and are only used by the kernel
-tests/benchmarks; models default to the XLA path.
+tests/benchmarks; models default to the XLA path. Interpret mode follows
+the backend and nothing else: a TPU process always compiles the kernels.
 """
 from __future__ import annotations
 
 import os
 from contextlib import contextmanager
+from typing import Optional
 
 _USE_PALLAS = os.environ.get("REPRO_USE_PALLAS", "0") == "1"
-_INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "")
 
 
 def use_pallas() -> bool:
@@ -23,19 +24,15 @@ def set_use_pallas(v: bool) -> None:
 
 
 def interpret_mode() -> bool:
-    """Interpret unless explicitly disabled (real TPU)."""
-    if _INTERPRET:
-        return _INTERPRET == "1"
+    """Interpret everywhere except on a TPU backend."""
     import jax
     return jax.default_backend() != "tpu"
 
 
-def tpu_compiler_params(**kwargs):
-    """Version-portable TPU compiler params: the class was renamed from
-    ``TPUCompilerParams`` to ``CompilerParams`` across JAX releases."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """A kernel entry point's ``interpret`` argument: ``None`` (the
+    default) follows :func:`interpret_mode`."""
+    return interpret_mode() if interpret is None else bool(interpret)
 
 
 @contextmanager

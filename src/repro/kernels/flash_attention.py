@@ -9,7 +9,8 @@ Block shapes default to 128x128 — MXU-aligned (128x128 systolic array),
 and the [BQ, hd] x [hd, BK] matmuls hit the MXU with no relayout.
 
 Validated against ref.attention_ref in interpret mode on CPU
-(tests/test_kernels.py sweeps shapes/dtypes); on TPU drop interpret.
+(tests/test_kernels.py sweeps shapes/dtypes); on a TPU the same entry
+point compiles.
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
+from repro.kernels.dispatch import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -78,7 +79,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention_bhsd(q, k, v, *, causal: bool = True,
                          window: Optional[int] = None, q_offset: int = 0,
                          bq: int = 128, bk: int = 128,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """q [B,H,Sq,hd]; k/v [B,KV,Sk,hd] -> o [B,H,Sq,hd]."""
     B, H, Sq, hd = q.shape
     KV, Sk = k.shape[1], k.shape[2]
@@ -115,9 +116,9 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out[:, :, :Sq]

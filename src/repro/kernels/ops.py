@@ -2,8 +2,9 @@
 
 Model code calls these through layers._dispatch_attention with the
 [B,S,H,hd] layout; the wrappers transpose to the kernels' [B,H,S,hd]
-blocked layout, handle GQA head mapping and padding, and pick interpret
-mode automatically (CPU containers interpret; real TPUs compile).
+blocked layout and handle GQA head mapping and padding. Each kernel
+resolves interpret mode from the backend (CPU interprets; a TPU
+compiles).
 """
 from __future__ import annotations
 
@@ -13,7 +14,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import dispatch
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.batched_decode_attention import batched_decode_attention_bhmd
 from repro.kernels.ragged_prefill_attention import ragged_prefill_attention_bhsd
@@ -29,8 +29,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     o = flash_attention_bhsd(qt, kt, vt, causal=causal, window=window,
-                             q_offset=q_offset, bq=bq, bk=bk,
-                             interpret=dispatch.interpret_mode())
+                             q_offset=q_offset, bq=bq, bk=bk)
     return jnp.swapaxes(o, 1, 2)
 
 
@@ -51,8 +50,7 @@ def ragged_prefill_attention(q, k, v, pos0, take, *,
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
     o = ragged_prefill_attention_bhsd(qt, kt, vt, pos0, take, window=window,
-                                      bq=bq, bk=bk,
-                                      interpret=dispatch.interpret_mode())
+                                      bq=bq, bk=bk)
     return jnp.swapaxes(o, 1, 2)
 
 
@@ -72,8 +70,7 @@ def decode_attention(q, k, v, *, kv_len, window: Optional[int] = None,
     kt = jnp.swapaxes(k, 1, 2)                       # [B,KV,M,hd]
     vt = jnp.swapaxes(v, 1, 2)
     o = batched_decode_attention_bhmd(qt, kt, vt, kv_len, window=window,
-                                      bk=bk,
-                                      interpret=dispatch.interpret_mode())
+                                      bk=bk)
     return o[:, None]
 
 
@@ -89,8 +86,7 @@ def chunked_gla(q, k, v, log_a, *, chunk: int = 128):
     kt = jnp.moveaxis(k, 1, 2)
     vt = jnp.moveaxis(v, 1, 2)
     lat = jnp.moveaxis(log_a, 1, 2)
-    y = chunked_gla_bhtd(qt, kt, vt, lat, chunk=chunk,
-                         interpret=dispatch.interpret_mode())
+    y = chunked_gla_bhtd(qt, kt, vt, lat, chunk=chunk)
     return jnp.moveaxis(y, 1, 2)
 
 
@@ -98,6 +94,5 @@ def chunked_gla(q, k, v, log_a, *, chunk: int = 128):
 def rmsnorm(x, scale, *, eps: float = 1e-5, bn: int = 256):
     """x [..., D] -> [..., D]."""
     shape = x.shape
-    y = rmsnorm_2d(x.reshape(-1, shape[-1]), scale, eps=eps, bn=bn,
-                   interpret=dispatch.interpret_mode())
+    y = rmsnorm_2d(x.reshape(-1, shape[-1]), scale, eps=eps, bn=bn)
     return y.reshape(shape)

@@ -58,7 +58,7 @@ block that is *computed* rather than skipped turns into NaN through the
 
 Validated against layers.ragged_prefill_attention (the jnp reference
 twin) in interpret mode on CPU (tests/test_ragged_prefill_kernel.py);
-on TPU drop interpret.
+on a TPU the same entry point compiles.
 """
 from __future__ import annotations
 
@@ -71,7 +71,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.dispatch import tpu_compiler_params
+from repro.kernels.dispatch import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -155,7 +155,7 @@ def _ragged_prefill_kernel(pos0_ref, take_ref, q_ref, k_ref, v_ref, o_ref,
 def ragged_prefill_attention_bhsd(q, k, v, pos0, take, *,
                                   window: Optional[int] = None,
                                   bq: int = 128, bk: int = 256,
-                                  interpret: bool = True):
+                                  interpret: Optional[bool] = None):
     """q [G,H,Sq,hd]; k/v [G,KV,W,hd]; pos0/take [G] -> o [G,H,Sq,hd]."""
     G, H, Sq, hd = q.shape
     KV, W = k.shape[1], k.shape[2]
@@ -201,8 +201,8 @@ def ragged_prefill_attention_bhsd(q, k, v, pos0, take, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, H, Sq + pq, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(jnp.asarray(pos0, jnp.int32), jnp.asarray(take, jnp.int32), q, k, v)
     return out[:, :, :Sq]

@@ -7,10 +7,13 @@ Row-tiled: grid (N/BN,); each program normalizes a [BN, D] block in VMEM
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.dispatch import resolve_interpret
 
 
 def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -21,7 +24,7 @@ def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_2d(x, scale, *, eps: float = 1e-5, bn: int = 256,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     """x [N, D], scale [D] -> [N, D]."""
     N, D = x.shape
     bn = min(bn, max(N, 8))
@@ -37,6 +40,6 @@ def rmsnorm_2d(x, scale, *, eps: float = 1e-5, bn: int = 256,
         ],
         out_specs=pl.BlockSpec((bn, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N + pn, D), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, scale)
     return out[:N]

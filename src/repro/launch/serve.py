@@ -25,8 +25,8 @@ cloud pool elastic — occupancy-driven grow/shrink with a modeled cold
 start, scale-to-zero on traffic gaps, poke-to-warm on the next
 arrival. Example::
 
-  PYTHONPATH=src python -m repro.launch.serve --rps 0.8 --duration 15 \
-      --cloud-replicas 3 --autoscale
+  PYTHONPATH=src python -m repro.launch.serve --reduced --rps 0.8 \
+      --duration 15 --cloud-replicas 3 --autoscale
 
 ``--faults SPEC`` drives a chaos run: deterministic seeded fault
 injection (cloud submit failures, stalls, replica crash/stragglers —
@@ -34,18 +34,28 @@ see ``serving.faults.FaultPlan.parse``) absorbed by scheduler-side
 recovery (``--max-retries`` / ``--timeout`` / ``--backoff-base``) and
 pool-side replica failover. Example::
 
-  PYTHONPATH=src python -m repro.launch.serve --queries 12 \
+  PYTHONPATH=src python -m repro.launch.serve --reduced --queries 12 \
       --cloud-replicas 2 --faults "submit_fail=0.1,crash=1@20,seed=0"
 
-On TPU the cloud engine would run the large model on the production mesh;
-on this container both engines run reduced configs on CPU (same code).
+Both engines serve their architecture at its published widths, with
+weights and KV cache in the config's dtype (bfloat16). Each engine holds
+its whole model on one device, so the pair must fit there: on one TPU
+v5e, ``--edge-arch qwen2-1.5b --cloud-arch qwen2-1.5b --max-len 2048``
+does (``chip_smoke.py`` runs exactly that). ``--reduced`` swaps in the
+2-layer CPU smoke variant of each config (``ModelConfig.reduced``) —
+the size for CPU runs and tests; width never depends on the backend::
 
-  PYTHONPATH=src python -m repro.launch.serve --queries 6
+  PYTHONPATH=src python -m repro.launch.serve --reduced --queries 6
+
+``main(argv)`` returns the ``RuntimeReport`` (with each engine's counters
+under ``stats["edge_engine"]`` / ``stats["cloud_engine"]``), so scripts
+drive the launcher's own code path.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,16 +67,52 @@ from repro.core.planner import SyntheticPlanner
 from repro.core.profiler import train_default_router
 from repro.core.exposure import mean_exposure
 from repro.data.tasks import gen_benchmark, WorldModel
+from repro.models import kvcache as KV
 from repro.models import model as M
 from repro.serving import (AutoscalePolicy, ServingConfig, ServingRuntime,
                            Trace)
 from repro.serving.engine import ServingEngine, JAXExecutor
 
+REDUCED_MAX_LEN = 192
+FULL_MAX_LEN = 2048
 
-def main():
+
+def build_engines(edge_arch: str, cloud_arch: str, *, reduced: bool,
+                  max_len: Optional[int] = None, prefill_chunk: int = 64,
+                  prefix_reuse: bool = True,
+                  prefix_block: int = KV.PREFIX_BLOCK):
+    """The (edge, cloud) serving engines, 2 and 4 KV slots, with weights
+    drawn from fixed seeds (0 and 1). Published widths unless
+    ``reduced``; params and cache in the config's dtype either way.
+    ``max_len`` defaults by size: 2048 lines per slot, 192 reduced."""
+    max_len = max_len or (REDUCED_MAX_LEN if reduced else FULL_MAX_LEN)
+
+    def engine(arch, seed, slots):
+        cfg = get_config(arch)
+        if reduced:
+            cfg = cfg.reduced()
+        # one jitted init: a single compile (which the persistent cache
+        # keeps) instead of one small eager compile per parameter shape
+        params = jax.jit(M.init_params, static_argnums=0)(
+            cfg, jax.random.PRNGKey(seed))
+        return ServingEngine(cfg, params, batch_slots=slots, max_len=max_len,
+                             dtype=jnp.dtype(cfg.dtype),
+                             prefill_chunk=prefill_chunk,
+                             prefix_reuse=prefix_reuse,
+                             prefix_block=prefix_block)
+    return engine(edge_arch, 0, 2), engine(cloud_arch, 1, 4)
+
+
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--edge-arch", default=PAPER_EDGE_ARCH, choices=ARCH_IDS)
     ap.add_argument("--cloud-arch", default=PAPER_CLOUD_ARCH, choices=ARCH_IDS)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the 2-layer CPU smoke variant of each "
+                         "config instead of its published widths")
+    ap.add_argument("--max-len", type=int, default=None,
+                    help=f"KV lines per slot (default {FULL_MAX_LEN}; "
+                         f"{REDUCED_MAX_LEN} with --reduced)")
     ap.add_argument("--queries", type=int, default=6,
                     help="closed-loop batch size (open loop: trace decides)")
     ap.add_argument("--benchmark", default="gpqa")
@@ -138,7 +184,7 @@ def main():
                        help="per-attempt deadline in seconds")
     chaos.add_argument("--backoff-base", type=float, default=0.05,
                        help="base of the capped exponential retry backoff")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     trace = None
     if args.trace is not None:
@@ -149,20 +195,11 @@ def main():
         ap.error("--sequential is closed-loop; drop --rps/--trace")
 
     wm = WorldModel()
-    edge_cfg = get_config(args.edge_arch).reduced()
-    cloud_cfg = get_config(args.cloud_arch).reduced().variant(n_layers=2)
-    from repro.models import kvcache as KV
-    eng_kw = dict(max_len=192, prefill_chunk=args.prefill_chunk,
-                  prefix_reuse=not args.no_prefix_reuse,
-                  prefix_block=args.prefix_block or KV.PREFIX_BLOCK)
-    edge_engine = ServingEngine(
-        edge_cfg, M.init_params(edge_cfg, jax.random.PRNGKey(0),
-                                dtype=jnp.float32),
-        batch_slots=2, **eng_kw)
-    cloud_engine = ServingEngine(
-        cloud_cfg, M.init_params(cloud_cfg, jax.random.PRNGKey(1),
-                                 dtype=jnp.float32),
-        batch_slots=4, **eng_kw)
+    edge_engine, cloud_engine = build_engines(
+        args.edge_arch, args.cloud_arch, reduced=args.reduced,
+        max_len=args.max_len, prefill_chunk=args.prefill_chunk,
+        prefix_reuse=not args.no_prefix_reuse,
+        prefix_block=args.prefix_block or KV.PREFIX_BLOCK)
     edge = JAXExecutor(edge_engine, wm, cloud=False, concurrency=1)
     # concurrency derives from engine capacity; with --cloud-replicas the
     # runtime scales this executor out to an EnginePool (replicas x slots)
@@ -255,7 +292,12 @@ def main():
                   f"requests={o['requests']} "
                   f"peak_active={o['peak_active']}/{o['slots']} "
                   f"slot_reuses={o['slot_reuses']}")
+    report.stats["edge_engine"] = dict(edge_engine.stats)
+    report.stats["cloud_engine"] = dict(cloud_eng.stats)
+    return report
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.enable()
     main()

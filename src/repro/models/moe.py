@@ -123,21 +123,16 @@ def moe_forward_ep(p, cfg, x, mesh) -> Tuple[jnp.ndarray, jnp.ndarray]:
     of the gather dispatch, whose all-to-all/all-gather volume made the
     MoE train shapes collective-bound (see EXPERIMENTS.md §Perf #1).
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    # check_vma=False: with jax 0.8's varying-manual-axes checker enabled,
-    # the TRANSPOSE of this body (sort+scatter over an input replicated on
+    # check_vma=False: with the varying-manual-axes checker enabled, the
+    # TRANSPOSE of this body (sort+scatter over an input replicated on
     # "model", sharded on the data axes) produces silently wrong router
     # gradients on mixed meshes (verified against finite differences —
     # tests/test_moe_ep.py). With the checker off, gradients match the
     # dense oracle to 5e-7.
-    try:
-        from jax import shard_map
-        _smap = lambda f, m, ins, outs: shard_map(
-            f, mesh=m, in_specs=ins, out_specs=outs, check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-        _smap = lambda f, m, ins, outs: _sm(f, m, in_specs=ins, out_specs=outs,
-                                            check_rep=False)
+    _smap = lambda f, m, ins, outs: shard_map(
+        f, mesh=m, in_specs=ins, out_specs=outs, check_vma=False)
 
     B, Sq, D = x.shape
     E, K = cfg.n_experts, cfg.top_k

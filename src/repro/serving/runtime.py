@@ -362,7 +362,8 @@ class ServingRuntime:
             stats[f"{name}_replica_requests"] = [o["requests"]
                                                  for o in occ()]
             stats[f"{name}_pump_passes"] = eng.pool_stats["pump_passes"]
-            for key in ("deaths", "failovers", "suspects", "hedges"):
+            for key in ("deaths", "failovers", "suspects", "hedges",
+                        "replica_errors"):
                 if key in eng.pool_stats:
                     stats[f"{name}_{key}"] = eng.pool_stats[key]
             health = getattr(eng, "health", None)

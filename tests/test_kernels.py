@@ -147,3 +147,49 @@ def test_model_path_with_pallas_enabled():
         pl_logits, _ = M.forward(params, cfg, batch)
     np.testing.assert_allclose(np.asarray(pl_logits), np.asarray(ref_logits),
                                atol=5e-3, rtol=5e-3)
+
+
+# ---- interpret-mode dispatch ----------------------------------------------
+
+def test_interpret_mode_follows_backend_only(monkeypatch):
+    """No environment variable can force interpret mode either way: it
+    is the backend's call (CPU interprets, a TPU compiles)."""
+    from repro.kernels import dispatch
+    for value in ("0", "1"):
+        monkeypatch.setenv("REPRO_PALLAS_INTERPRET", value)
+        assert dispatch.interpret_mode() == (jax.default_backend() != "tpu")
+    assert dispatch.resolve_interpret(None) == dispatch.interpret_mode()
+    assert dispatch.resolve_interpret(False) is False
+    assert dispatch.resolve_interpret(True) is True
+
+
+KERNEL_ENTRY_POINTS = [
+    ("ragged_prefill_attention", "ragged_prefill_attention_bhsd"),
+    ("batched_decode_attention", "batched_decode_attention_bhmd"),
+    ("decode_attention", "decode_attention_bhmd"),
+    ("flash_attention", "flash_attention_bhsd"),
+    ("rmsnorm", "rmsnorm_2d"),
+    ("chunked_gla", "chunked_gla_bhtd"),
+]
+
+
+@pytest.mark.parametrize("module,name", KERNEL_ENTRY_POINTS)
+def test_kernel_entry_point_interpret_defaults_to_backend(module, name):
+    import importlib
+    import inspect
+    fn = getattr(importlib.import_module(f"repro.kernels.{module}"), name)
+    assert inspect.signature(fn).parameters["interpret"].default is None
+
+
+def test_omitted_interpret_resolves_to_interpret_on_cpu():
+    """A kernel called without ``interpret`` on the CPU backend runs in
+    interpret mode (it cannot compile for the CPU) and matches its
+    oracle."""
+    from repro.kernels.rmsnorm import rmsnorm_2d
+    assert jax.default_backend() == "cpu"
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    x = _rand(ks[0], (16, 128), jnp.float32)
+    s = _rand(ks[1], (128,), jnp.float32)
+    np.testing.assert_allclose(np.asarray(rmsnorm_2d(x, s)),
+                               np.asarray(ref.rmsnorm_ref(x, s)),
+                               atol=2e-3, rtol=2e-3)
