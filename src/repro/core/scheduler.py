@@ -47,6 +47,7 @@ import numpy as np
 from repro.core.dag import PlanDAG, Node, topological_order
 from repro.core.dual import TwoBudgetThreshold
 from repro.data.tasks import Query, Subtask, WorldModel
+from repro.tracing import span
 
 
 class Executor(Protocol):
@@ -78,6 +79,8 @@ class SubtaskResult:
     answer: str = ""
     retries: int = 0           # failed attempts absorbed before this result
     degraded: bool = False     # cloud subtask that fell back to the edge
+    dispatch_wait: float = 0.0  # fleet clock from routed to the start of
+    #                             the attempt that produced this result
 
 
 @dataclass(frozen=True)
@@ -111,6 +114,8 @@ class _Dispatch:
     retries: int = 0           # total failed attempts, both sides
     degraded: bool = False
     not_before: float = 0.0    # backoff gate (fleet-clock seconds)
+    ready_at: float = 0.0      # fleet clock at routing (dispatch_wait's
+    #                            origin)
     hint: Optional[object] = None  # KV prefix hint (shared-context token
     #                            ids); computed once at first dispatch and
     #                            carried — because the dispatch is mutated
@@ -399,6 +404,7 @@ class FleetScheduler:
         if disp is not None:
             res.retries = disp.retries
             res.degraded = disp.degraded
+            res.dispatch_wait = start - disp.ready_at
         if qs.first_done is None:
             qs.first_done = end    # TTFT anchor: first visible output
         qs.done_sids.add(node.sid)
@@ -493,14 +499,15 @@ class FleetScheduler:
             for node in list(qs.ready):
                 qs.ready.remove(node)
                 qs.ctx.extra["clock"] = st.clock - qs.admit_clock
-                r, _info = qs.policy.decide(qs.query, node, qs.ctx)
+                with span("sched.route"):
+                    r, _info = qs.policy.decide(qs.query, node, qs.ctx)
                 if (r and self.global_budget is not None
                         and self.global_budget.tau >= 1.0):
                     r = 0
                     self.stats["forced_edge"] += 1
                 qs.offload[node.sid] = r
                 qs.ctx.position += 1
-                qs.waiting.append(_Dispatch(r, node))
+                qs.waiting.append(_Dispatch(r, node, ready_at=st.clock))
 
         def dispatch_one(qs: _QueryState) -> bool:
             for j, disp in enumerate(qs.waiting):
@@ -544,12 +551,13 @@ class FleetScheduler:
             # subtask
             any_progress = False
             progressed = True
-            while progressed:
-                progressed = False
-                for qs in st.active:
-                    if qs.waiting:
-                        progressed |= dispatch_one(qs)
-                any_progress |= progressed
+            with span("sched.dispatch"):
+                while progressed:
+                    progressed = False
+                    for qs in st.active:
+                        if qs.waiting:
+                            progressed |= dispatch_one(qs)
+                    any_progress |= progressed
             return any_progress
 
         return admit_next, route_ready, dispatch_all
@@ -723,104 +731,110 @@ class FleetScheduler:
         dispatch_all()
         while inflight or any(qs.waiting for qs in st.active) \
                 or (timed and st.backlog):
-            stepped = False
-            for ex in pools:
-                stepped |= bool(ex.pump())
-            st.clock = time.perf_counter() - t0
-            if timed and st.backlog:
-                admit_next()
-                if dispatch_all():
-                    # freshly arrived work was placed; poll it next pass
-                    idle_since = st.clock
-                    continue
-                if not inflight and not any(qs.waiting
-                                            for qs in st.active):
-                    # traffic gap: everything admitted has drained and the
-                    # next arrival is in the future — keep pumping pools
-                    # (autoscalers tick on wall-clock) and nap briefly
-                    time.sleep(min(max(st.backlog[0].arrival - st.clock,
-                                       0.0), 0.002))
-                    idle_since = st.clock
-                    continue
-            fault_fired = False
-            if timeout_s is not None:
-                for row in [r_ for r_ in inflight
-                            if st.clock - r_[4] > timeout_s]:
-                    fut, qs, disp, ex, start = row
-                    inflight.remove(row)
-                    st.busy[id(ex)] -= 1
-                    cancel = getattr(ex, "cancel", None)
-                    if cancel is not None:
-                        cancel(fut)
-                    # tokens the engine already decoded for the abandoned
-                    # attempt were paid for — charge them
-                    cost_fn = getattr(ex, "attempt_cost", None)
-                    cost = float(cost_fn(fut)) if cost_fn is not None \
-                        else 0.0
-                    self.stats["timeouts"] += 1
-                    self._charge_fault(qs, cost, st.clock - start,
-                                       dl=st.clock - prev_clock)
-                    prev_clock = st.clock
-                    self._handle_fault(
-                        qs, disp, RuntimeError(
-                            f"subtask (qid={qs.query.qid}, "
-                            f"sid={disp.node.sid}) exceeded deadline "
-                            f"{timeout_s}s in flight"), requeue)
-                    fault_fired = True
-            done_rows = []
-            for row in inflight:
-                res = row[3].poll(row[0])
-                if res is not None:
-                    done_rows.append((row, res))
-            # same-tick completions are observed in (qid, sid) order, not
-            # engine-poll order: policies shared across the fleet (e.g. a
-            # HybridFlowPolicy LinUCB calibrator) then see an update
-            # sequence that is stable across runs/replica counts even
-            # when co-batched subtasks finish on the same pump pass
-            done_rows.sort(key=lambda dr: (dr[0][1].query.qid,
-                                           dr[0][2].node.sid))
-            if not done_rows and not fault_fired:
-                if self.retry is None:
-                    # pre-recovery contract, preserved exactly: an idle
-                    # pass with work in flight is a wiring bug
-                    if not stepped:
-                        raise RuntimeError(
-                            "fleet pump stalled: subtasks in flight but "
-                            "every engine is idle (executor/engine "
-                            "mismatch?)")
-                    continue
-                # recovery enabled: idle passes are expected (backoff
-                # gates, injected stalls) — give backoff-expired work a
-                # dispatch chance, and only past the grace window does an
-                # idle fleet become a hard stall
-                if bool(dispatch_all()) or stepped:
-                    idle_since = st.clock
-                elif st.clock - idle_since > self.stall_grace:
-                    raise RuntimeError(
-                        f"fleet pump stalled for "
-                        f"{st.clock - idle_since:.1f}s (grace "
-                        f"{self.stall_grace:.1f}s) with {len(inflight)} "
-                        f"subtasks in flight:\n"
-                        + "\n".join(self._stuck_dump(qs)
-                                    for qs in st.active))
-                else:
-                    time.sleep(0.001)
-                continue
-            for row, res in done_rows:
-                _fut, qs, disp, ex, start = row
-                inflight.remove(row)
-                st.busy[id(ex)] -= 1
-                qs.results[disp.node.sid] = res
-                self._observe_completion(qs, disp.node, disp.r, res, start,
-                                         st.clock, prev_clock, disp=disp)
-                prev_clock = st.clock
-                route_ready(qs)
-                if qs.n_done == qs.dag.n:
-                    self._finalize(qs, st.clock)
-                    st.active.remove(qs)
+            with span("sched.pass"):
+                stepped = False
+                for ex in pools:
+                    stepped |= bool(ex.pump())
+                st.clock = time.perf_counter() - t0
+                if timed and st.backlog:
                     admit_next()
-            dispatch_all()
-            idle_since = st.clock
+                    if dispatch_all():
+                        # freshly arrived work was placed; poll it next pass
+                        idle_since = st.clock
+                        continue
+                    if not inflight and not any(qs.waiting
+                                                for qs in st.active):
+                        # traffic gap: everything admitted has drained and the
+                        # next arrival is in the future — keep pumping pools
+                        # (autoscalers tick on wall-clock) and nap briefly
+                        with span("sched.nap"):
+                            time.sleep(min(max(st.backlog[0].arrival
+                                               - st.clock, 0.0), 0.002))
+                        idle_since = st.clock
+                        continue
+                fault_fired = False
+                if timeout_s is not None:
+                    for row in [r_ for r_ in inflight
+                                if st.clock - r_[4] > timeout_s]:
+                        fut, qs, disp, ex, start = row
+                        inflight.remove(row)
+                        st.busy[id(ex)] -= 1
+                        cancel = getattr(ex, "cancel", None)
+                        if cancel is not None:
+                            cancel(fut)
+                        # tokens the engine already decoded for the abandoned
+                        # attempt were paid for — charge them
+                        cost_fn = getattr(ex, "attempt_cost", None)
+                        cost = float(cost_fn(fut)) if cost_fn is not None \
+                            else 0.0
+                        self.stats["timeouts"] += 1
+                        self._charge_fault(qs, cost, st.clock - start,
+                                           dl=st.clock - prev_clock)
+                        prev_clock = st.clock
+                        self._handle_fault(
+                            qs, disp, RuntimeError(
+                                f"subtask (qid={qs.query.qid}, "
+                                f"sid={disp.node.sid}) exceeded deadline "
+                                f"{timeout_s}s in flight"), requeue)
+                        fault_fired = True
+                done_rows = []
+                with span("sched.poll"):
+                    for row in inflight:
+                        res = row[3].poll(row[0])
+                        if res is not None:
+                            done_rows.append((row, res))
+                # same-tick completions are observed in (qid, sid) order, not
+                # engine-poll order: policies shared across the fleet (e.g. a
+                # HybridFlowPolicy LinUCB calibrator) then see an update
+                # sequence that is stable across runs/replica counts even
+                # when co-batched subtasks finish on the same pump pass
+                done_rows.sort(key=lambda dr: (dr[0][1].query.qid,
+                                               dr[0][2].node.sid))
+                if not done_rows and not fault_fired:
+                    if self.retry is None:
+                        # pre-recovery contract, preserved exactly: an idle
+                        # pass with work in flight is a wiring bug
+                        if not stepped:
+                            raise RuntimeError(
+                                "fleet pump stalled: subtasks in flight but "
+                                "every engine is idle (executor/engine "
+                                "mismatch?)")
+                        continue
+                    # recovery enabled: idle passes are expected (backoff
+                    # gates, injected stalls) — give backoff-expired work a
+                    # dispatch chance, and only past the grace window does an
+                    # idle fleet become a hard stall
+                    if bool(dispatch_all()) or stepped:
+                        idle_since = st.clock
+                    elif st.clock - idle_since > self.stall_grace:
+                        raise RuntimeError(
+                            f"fleet pump stalled for "
+                            f"{st.clock - idle_since:.1f}s (grace "
+                            f"{self.stall_grace:.1f}s) with {len(inflight)} "
+                            f"subtasks in flight:\n"
+                            + "\n".join(self._stuck_dump(qs)
+                                        for qs in st.active))
+                    else:
+                        with span("sched.nap"):
+                            time.sleep(0.001)
+                    continue
+                with span("sched.complete"):
+                    for row, res in done_rows:
+                        _fut, qs, disp, ex, start = row
+                        inflight.remove(row)
+                        st.busy[id(ex)] -= 1
+                        qs.results[disp.node.sid] = res
+                        self._observe_completion(qs, disp.node, disp.r, res,
+                                                 start, st.clock, prev_clock,
+                                                 disp=disp)
+                        prev_clock = st.clock
+                        route_ready(qs)
+                        if qs.n_done == qs.dag.n:
+                            self._finalize(qs, st.clock)
+                            st.active.remove(qs)
+                            admit_next()
+                dispatch_all()
+                idle_since = st.clock
 
         self.makespan = st.clock
         return self._collect_results()

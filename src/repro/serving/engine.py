@@ -75,6 +75,7 @@ from repro.configs.base import ModelConfig
 from repro.data import tokenizer as tok
 from repro.models import kvcache as KV
 from repro.models import model as M
+from repro.tracing import span
 
 
 @dataclass
@@ -86,8 +87,6 @@ class Request:
     # filled by the engine:
     output_ids: List[int] = field(default_factory=list)
     done: bool = False
-    submitted_at: float = 0.0
-    finished_at: float = 0.0
 
     @property
     def text(self) -> str:
@@ -347,7 +346,11 @@ class ServingEngine:
                       "prefill_calls": 0, "prefill_batch_max": 0,
                       "prefill_backend": None, "jit_retraces": 0,
                       "prefix_hits": 0, "prefill_tokens_saved": 0,
-                      "prefix_copies": 0}
+                      "prefix_copies": 0,
+                      # rows and positions the step programs compute,
+                      # padding included: beside tokens_out and
+                      # prefill_tokens they give the padded share
+                      "decode_rows": 0, "prefill_positions": 0}
 
     def _steps(self):
         """Resolve the jitted step pair against the CURRENT kernel-dispatch
@@ -402,8 +405,7 @@ class ServingEngine:
                 f"max_new_tokens <= max_len - 2)")
         ids = tok.encode(prompt) if isinstance(prompt, str) else list(prompt)
         ids = [min(i, self.cfg.vocab_size - 1) for i in ids]
-        req = Request(self._rid, ids, max_new_tokens, temperature,
-                      submitted_at=time.time())
+        req = Request(self._rid, ids, max_new_tokens, temperature)
         req._engine = self            # ownership marker for run_until
         self._rid += 1
         self.queue.append(req)
@@ -508,42 +510,44 @@ class ServingEngine:
 
     # ---- engine internals ----------------------------------------------
     def _admit(self) -> None:
-        for slot in range(self.slots):
-            if (self.active[slot] is None and self.queue
-                    and slot not in self._pinned):
-                req = self.queue.pop(0)
-                self.active[slot] = req
-                # slot lease accounting: KV lines are a fixed pool; a
-                # reused slot means the cache allocation was recycled
-                # rather than grown (the bounded-pool invariant)
-                if self._slot_used[slot]:
-                    self.stats["slot_reuses"] += 1
-                self._slot_used[slot] = True
-                self.stats["requests"] += 1
-                ids = req.prompt_ids[-(self.max_len - req.max_new_tokens - 1):]
-                if self.batched_prefill:
-                    job = _PrefillJob(ids)
-                    if self.prefix_reuse:
-                        # match BEFORE evicting this slot's own entry: if
-                        # the best source is the slot we just leased, its
-                        # prefix lines are already in place (in-place
-                        # reuse, no copy); otherwise pin the source so no
-                        # later lease overwrites it before the batched
-                        # seed copy launches.
-                        src, n = self._prefix.match(ids)
-                        if n > 0:
-                            job.off = n
-                            self.stats["prefix_hits"] += 1
-                            self.stats["prefill_tokens_saved"] += n
-                            if src != slot:
-                                self._pending_copy.append((slot, src, n))
-                                self._pinned.add(src)
-                        self._prefix.evict(slot)
-                    self._prefilling[slot] = job
-                else:
-                    self._prefill_slot_legacy(slot, req, ids)
-        self.stats["peak_active"] = max(self.stats["peak_active"],
-                                        self.n_active)
+        with span("engine.admit"):
+            for slot in range(self.slots):
+                if (self.active[slot] is None and self.queue
+                        and slot not in self._pinned):
+                    req = self.queue.pop(0)
+                    self.active[slot] = req
+                    # slot lease accounting: KV lines are a fixed pool; a
+                    # reused slot means the cache allocation was recycled
+                    # rather than grown (the bounded-pool invariant)
+                    if self._slot_used[slot]:
+                        self.stats["slot_reuses"] += 1
+                    self._slot_used[slot] = True
+                    self.stats["requests"] += 1
+                    ids = req.prompt_ids[
+                        -(self.max_len - req.max_new_tokens - 1):]
+                    if self.batched_prefill:
+                        job = _PrefillJob(ids)
+                        if self.prefix_reuse:
+                            # match BEFORE evicting this slot's own entry: if
+                            # the best source is the slot we just leased, its
+                            # prefix lines are already in place (in-place
+                            # reuse, no copy); otherwise pin the source so no
+                            # later lease overwrites it before the batched
+                            # seed copy launches.
+                            src, n = self._prefix.match(ids)
+                            if n > 0:
+                                job.off = n
+                                self.stats["prefix_hits"] += 1
+                                self.stats["prefill_tokens_saved"] += n
+                                if src != slot:
+                                    self._pending_copy.append((slot, src, n))
+                                    self._pinned.add(src)
+                            self._prefix.evict(slot)
+                        self._prefilling[slot] = job
+                    else:
+                        self._prefill_slot_legacy(slot, req, ids)
+            self.stats["peak_active"] = max(self.stats["peak_active"],
+                                            self.n_active)
 
     def _bucket(self, n: int) -> int:
         """Pad chunk width to a power-of-two bucket (bounded jit compiles)."""
@@ -558,69 +562,73 @@ class ServingEngine:
         ``serve_prefill_chunk`` call for the whole group. Host bookkeeping
         is deferred to ``_prefill_commit`` so a pool can overlap another
         replica's launch with this one's device compute."""
-        if self._pending_copy:
-            # seed newly leased slots from their matched sources in ONE
-            # batched copy, issued BEFORE this step's prefill writes: the
-            # copy reads the pre-step cache value (XLA data ordering), so
-            # even a source re-leased in the same admit pass is read
-            # intact. Pins release here — after this the borrowers own
-            # their lines and sources are free to be overwritten.
-            dst = np.asarray([c[0] for c in self._pending_copy], np.int32)
-            src = np.asarray([c[1] for c in self._pending_copy], np.int32)
-            ln = np.asarray([c[2] for c in self._pending_copy], np.int32)
-            width = self._bucket(int(ln.max()))
-            self.cache = _jit_copy(width)(
-                self.cache, jnp.asarray(src), jnp.asarray(dst),
-                jnp.asarray(ln))  # donate+rebind: reprolint-clean idiom
-            self.stats["prefix_copies"] += len(self._pending_copy)
-            self._pending_copy.clear()
-            self._pinned.clear()
+        with span("engine.prefill_launch"):
+            if self._pending_copy:
+                # seed newly leased slots from their matched sources in ONE
+                # batched copy, issued BEFORE this step's prefill writes: the
+                # copy reads the pre-step cache value (XLA data ordering), so
+                # even a source re-leased in the same admit pass is read
+                # intact. Pins release here — after this the borrowers own
+                # their lines and sources are free to be overwritten.
+                dst = np.asarray([c[0] for c in self._pending_copy], np.int32)
+                src = np.asarray([c[1] for c in self._pending_copy], np.int32)
+                ln = np.asarray([c[2] for c in self._pending_copy], np.int32)
+                width = self._bucket(int(ln.max()))
+                self.cache = _jit_copy(width)(
+                    self.cache, jnp.asarray(src), jnp.asarray(dst),
+                    jnp.asarray(ln))  # donate+rebind: reprolint-clean idiom
+                self.stats["prefix_copies"] += len(self._pending_copy)
+                self._pending_copy.clear()
+                self._pinned.clear()
+                self._track_retraces()
+            if not self._prefilling:
+                return None
+            jobs = sorted(self._prefilling.items())
+            chunk = self.prefill_chunk or self.max_len
+            take = [min(j.remaining, chunk) for _, j in jobs]
+            width = self._bucket(max(take))
+            g = len(jobs)
+            tokens = np.zeros((g, width), np.int32)
+            pos0 = np.zeros(g, np.int32)
+            slot_idx = np.zeros(g, np.int32)
+            temps = np.zeros(g, np.float32)
+            for i, (slot, j) in enumerate(jobs):
+                tokens[i, :take[i]] = j.ids[j.off:j.off + take[i]]
+                pos0[i] = j.off
+                slot_idx[i] = slot
+                temps[i] = self.active[slot].temperature
+            # kv_width is pinned to the SAME power-of-two bucket ladder as
+            # the chunk width (static jit arg): a mixed-length fleet can only
+            # ever produce O(log(max_len)) distinct kv_width values, so the
+            # (g, width, kv_width) retrace space stays bounded no matter how
+            # prompt lengths vary step to step (stats["jit_retraces"]).
+            kv_width = self._bucket(int(max(pos0[i] + take[i]
+                                            for i in range(g))))
+            from repro.kernels import dispatch as kd
+            self.stats["prefill_backend"] = ("pallas" if kd.use_pallas()
+                                             else "xla")
+            _, prefill_step = self._steps()
+            first, self.pos, self.cache, self.key = prefill_step(
+                self.params, jnp.asarray(tokens), jnp.asarray(slot_idx),
+                jnp.asarray(pos0),
+                # host->device upload of a Python list, not a device sync
+                # reprolint: disable=host-sync-in-hot-path -- take is a host list; np.asarray builds the upload buffer
+                jnp.asarray(np.asarray(take, np.int32)),
+                self.pos, self.cache, self.key, jnp.asarray(temps), kv_width)
+            self.stats["prefill_calls"] += 1
+            self.stats["prefill_positions"] += g * width
+            self.stats["prefill_batch_max"] = max(
+                self.stats["prefill_batch_max"], g)
             self._track_retraces()
-        if not self._prefilling:
-            return None
-        jobs = sorted(self._prefilling.items())
-        chunk = self.prefill_chunk or self.max_len
-        take = [min(j.remaining, chunk) for _, j in jobs]
-        width = self._bucket(max(take))
-        g = len(jobs)
-        tokens = np.zeros((g, width), np.int32)
-        pos0 = np.zeros(g, np.int32)
-        slot_idx = np.zeros(g, np.int32)
-        temps = np.zeros(g, np.float32)
-        for i, (slot, j) in enumerate(jobs):
-            tokens[i, :take[i]] = j.ids[j.off:j.off + take[i]]
-            pos0[i] = j.off
-            slot_idx[i] = slot
-            temps[i] = self.active[slot].temperature
-        # kv_width is pinned to the SAME power-of-two bucket ladder as the
-        # chunk width (static jit arg): a mixed-length fleet can only ever
-        # produce O(log(max_len)) distinct kv_width values, so the
-        # (g, width, kv_width) retrace space stays bounded no matter how
-        # prompt lengths vary step to step (stats["jit_retraces"]).
-        kv_width = self._bucket(int(max(pos0[i] + take[i]
-                                        for i in range(g))))
-        from repro.kernels import dispatch as kd
-        self.stats["prefill_backend"] = "pallas" if kd.use_pallas() else "xla"
-        _, prefill_step = self._steps()
-        first, self.pos, self.cache, self.key = prefill_step(
-            self.params, jnp.asarray(tokens), jnp.asarray(slot_idx),
-            jnp.asarray(pos0),
-            # host->device upload of a Python list, not a device sync
-            # reprolint: disable=host-sync-in-hot-path -- take is a host list; np.asarray builds the upload buffer
-            jnp.asarray(np.asarray(take, np.int32)),
-            self.pos, self.cache, self.key, jnp.asarray(temps), kv_width)
-        self.stats["prefill_calls"] += 1
-        self.stats["prefill_batch_max"] = max(
-            self.stats["prefill_batch_max"], g)
-        self._track_retraces()
-        return _PrefillPass(jobs, take, first)
+            return _PrefillPass(jobs, take, first)
 
     # reprolint: hot
     def _prefill_commit(self, p: _PrefillPass) -> None:
         """Sync the launched prefill chunk and advance the per-slot jobs
         (first sampled token, slot positions, finished-job retirement)."""
-        # reprolint: disable=host-sync-in-hot-path -- the ONE host transfer per prefill pass (sampled first tokens)
-        first_np = np.asarray(p.first)
+        with span("engine.sync"):
+            # reprolint: disable=host-sync-in-hot-path -- the ONE host transfer per prefill pass (sampled first tokens)
+            first_np = np.asarray(p.first)
         for i, (slot, j) in enumerate(p.jobs):
             j.off += p.take[i]
             self.stats["prefill_tokens"] += p.take[i]
@@ -687,29 +695,32 @@ class ServingEngine:
     def _decode_launch(self) -> Optional[_DecodePass]:
         """Launch one decode token for every live (fully prefilled) slot;
         host bookkeeping is deferred to ``_decode_commit``."""
-        live_slots = [i for i, r in enumerate(self.active)
-                      if r is not None and i not in self._prefilling]
-        if not live_slots:
-            return None
-        tokens = np.zeros((self.slots, 1), np.int32)
-        temps = np.zeros(self.slots, np.float32)
-        live = np.zeros(self.slots, np.int32)
-        for i in live_slots:
-            tokens[i, 0] = self.active[i].output_ids[-1]
-            temps[i] = self.active[i].temperature
-            live[i] = 1
-        decode_step, _ = self._steps()
-        nxt, self.pos, self.cache, self.key = decode_step(
-            self.params, jnp.asarray(tokens), self.pos, self.cache,
-            self.key, jnp.asarray(temps), jnp.asarray(live))
-        self._track_retraces()
-        return _DecodePass(live_slots, nxt)
+        with span("engine.decode_launch"):
+            live_slots = [i for i, r in enumerate(self.active)
+                          if r is not None and i not in self._prefilling]
+            if not live_slots:
+                return None
+            tokens = np.zeros((self.slots, 1), np.int32)
+            temps = np.zeros(self.slots, np.float32)
+            live = np.zeros(self.slots, np.int32)
+            for i in live_slots:
+                tokens[i, 0] = self.active[i].output_ids[-1]
+                temps[i] = self.active[i].temperature
+                live[i] = 1
+            decode_step, _ = self._steps()
+            nxt, self.pos, self.cache, self.key = decode_step(
+                self.params, jnp.asarray(tokens), self.pos, self.cache,
+                self.key, jnp.asarray(temps), jnp.asarray(live))
+            self.stats["decode_rows"] += self.slots
+            self._track_retraces()
+            return _DecodePass(live_slots, nxt)
 
     # reprolint: hot
     def _decode_commit(self, d: _DecodePass) -> List[Request]:
         """Sync the launched decode step and retire finished requests."""
-        # reprolint: disable=host-sync-in-hot-path -- the ONE host transfer per decode step (sampled ids)
-        nxt_np = np.asarray(d.nxt)
+        with span("engine.sync"):
+            # reprolint: disable=host-sync-in-hot-path -- the ONE host transfer per decode step (sampled ids)
+            nxt_np = np.asarray(d.nxt)
         finished: List[Request] = []
         for i in d.live_slots:
             req = self.active[i]
@@ -720,7 +731,6 @@ class ServingEngine:
                     or nxt_np[i] == tok.EOS_ID
                     or self._pos_np[i] >= self.max_len - 1):
                 req.done = True
-                req.finished_at = time.time()
                 finished.append(req)
                 self.active[i] = None
         self.stats["steps"] += 1
@@ -732,12 +742,13 @@ class ServingEngine:
         prefilling slot by one chunk, then decode one token for all live
         slots (prefill and decode of co-resident requests interleave, so
         a long prompt never stalls running generations)."""
-        self._admit()
-        p = self._prefill_launch()
-        if p is not None:
-            self._prefill_commit(p)
-        d = self._decode_launch()
-        return self._decode_commit(d) if d is not None else []
+        with span("engine.step"):
+            self._admit()
+            p = self._prefill_launch()
+            if p is not None:
+                self._prefill_commit(p)
+            d = self._decode_launch()
+            return self._decode_commit(d) if d is not None else []
 
 
 @dataclass
@@ -832,13 +843,16 @@ class JAXExecutor:
     def submit(self, query, node, dep_results, *,
                prefix_hint: Optional[List[int]] = None) -> _Inflight:
         from repro.core.scheduler import _subtask_of
-        st = _subtask_of(query, node)
-        prompt = self.query_context(query) + node.desc + " || " + " ; ".join(
-            dep_results[d].answer for d in node.deps if d in dep_results)
-        n_bad = sum(1 for d in node.deps
-                    if d in dep_results and not dep_results[d].correct)
-        req = self.engine.submit(prompt, max_new_tokens=min(st.tok_out, 48),
-                                 prefix_hint=prefix_hint)
+        with span("exec.submit", qid=query.qid, sid=node.sid):
+            st = _subtask_of(query, node)
+            prompt = (self.query_context(query) + node.desc + " || "
+                      + " ; ".join(dep_results[d].answer for d in node.deps
+                                   if d in dep_results))
+            n_bad = sum(1 for d in node.deps
+                        if d in dep_results and not dep_results[d].correct)
+            req = self.engine.submit(prompt,
+                                     max_new_tokens=min(st.tok_out, 48),
+                                     prefix_hint=prefix_hint)
         return _Inflight(req, st.sid, self.cloud, st.difficulty, n_bad,
                          query, time.perf_counter())
 

@@ -104,6 +104,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.serving.engine import Request, ServingEngine
+from repro.tracing import span
 
 
 @dataclass(frozen=True)
@@ -498,75 +499,76 @@ class EnginePool:
         requests or deadlocks the join. Only *warm* replicas step —
         warming replicas hold their queues until the autoscaler promotes
         them (every replica is warm when no autoscaler is armed)."""
-        if self.autoscaler is not None:
-            self.autoscaler.tick()
-        loaded = [(i, self.engines[i]) for i in self._alive()
-                  if self.engines[i].has_work
-                  and self.lifecycle[i] == "warm"]
-        if not loaded:
-            return []
-        self.pool_stats["pump_passes"] += 1
-        finished: List[Request] = []
-        errors: List[Tuple[int, BaseException]] = []
-        if len(loaded) == 1:
-            i, e = loaded[0]
-            try:
-                finished = e.step()
-            except Exception as exc:
-                errors.append((i, exc))
-        elif self.threads:
-            if self._tp is None:
-                self._tp = ThreadPoolExecutor(
-                    max_workers=len(self.engines),
-                    thread_name_prefix="enginepool")
-            # one worker per loaded replica: replica state is thread-
-            # private, results join in replica-index order (determinism)
-            futs = [(i, self._tp.submit(e.step)) for i, e in loaded]
-            for i, f in futs:
+        with span("pool.step"):
+            if self.autoscaler is not None:
+                self.autoscaler.tick()
+            loaded = [(i, self.engines[i]) for i in self._alive()
+                      if self.engines[i].has_work
+                      and self.lifecycle[i] == "warm"]
+            if not loaded:
+                return []
+            self.pool_stats["pump_passes"] += 1
+            finished: List[Request] = []
+            errors: List[Tuple[int, BaseException]] = []
+            if len(loaded) == 1:
+                i, e = loaded[0]
                 try:
-                    finished.extend(f.result())
+                    finished = e.step()
                 except Exception as exc:
                     errors.append((i, exc))
-        else:
-            # launch-all/commit-all: a replica that raises in any phase
-            # drops out of the later phases of this pass
-            live = []
-            for i, e in loaded:
-                try:
-                    e._admit()
-                    live.append((i, e))
-                except Exception as exc:
-                    errors.append((i, exc))
-            prefills = []
-            for i, e in live:
-                try:
-                    prefills.append((i, e, e._prefill_launch()))
-                except Exception as exc:
-                    errors.append((i, exc))
-            live = []
-            for i, e, p in prefills:
-                try:
-                    if p is not None:
-                        e._prefill_commit(p)
-                    live.append((i, e))
-                except Exception as exc:
-                    errors.append((i, exc))
-            decodes = []
-            for i, e in live:
-                try:
-                    decodes.append((i, e, e._decode_launch()))
-                except Exception as exc:
-                    errors.append((i, exc))
-            for i, e, d in decodes:
-                try:
-                    if d is not None:
-                        finished.extend(e._decode_commit(d))
-                except Exception as exc:
-                    errors.append((i, exc))
-        for i, exc in errors:
-            self._kill_replica(i, exc)
-        self._update_health()
-        return finished
+            elif self.threads:
+                if self._tp is None:
+                    self._tp = ThreadPoolExecutor(
+                        max_workers=len(self.engines),
+                        thread_name_prefix="enginepool")
+                # one worker per loaded replica: replica state is thread-
+                # private, results join in replica-index order (determinism)
+                futs = [(i, self._tp.submit(e.step)) for i, e in loaded]
+                for i, f in futs:
+                    try:
+                        finished.extend(f.result())
+                    except Exception as exc:
+                        errors.append((i, exc))
+            else:
+                # launch-all/commit-all: a replica that raises in any phase
+                # drops out of the later phases of this pass
+                live = []
+                for i, e in loaded:
+                    try:
+                        e._admit()
+                        live.append((i, e))
+                    except Exception as exc:
+                        errors.append((i, exc))
+                prefills = []
+                for i, e in live:
+                    try:
+                        prefills.append((i, e, e._prefill_launch()))
+                    except Exception as exc:
+                        errors.append((i, exc))
+                live = []
+                for i, e, p in prefills:
+                    try:
+                        if p is not None:
+                            e._prefill_commit(p)
+                        live.append((i, e))
+                    except Exception as exc:
+                        errors.append((i, exc))
+                decodes = []
+                for i, e in live:
+                    try:
+                        decodes.append((i, e, e._decode_launch()))
+                    except Exception as exc:
+                        errors.append((i, exc))
+                for i, e, d in decodes:
+                    try:
+                        if d is not None:
+                            finished.extend(e._decode_commit(d))
+                    except Exception as exc:
+                        errors.append((i, exc))
+            for i, exc in errors:
+                self._kill_replica(i, exc)
+            self._update_health()
+            return finished
 
     # ---- failure handling ----------------------------------------------
     def _kill_replica(self, i: int, exc: BaseException) -> None:

@@ -1,0 +1,34 @@
+"""Host spans on the profiler's clock.
+
+``span(name, **ids)`` opens a ``jax.profiler.TraceAnnotation`` named
+``hf.<name>``. While a profiler trace is being taken the span lands in
+the trace's host plane, on the thread that opened it and on the device
+trace's clock, so device idle time can be put down to the host phase
+around it; otherwise it costs under a microsecond. There is no switch
+and no exporter. Pass ids (``qid``, ``sid``) only on per-subtask spans,
+never on per-pass or per-step ones.
+
+Spans, by layer:
+
+* scheduler (``core/scheduler.py``): ``sched.pass`` (one pump-loop
+  iteration), ``sched.poll``, ``sched.complete``, ``sched.route`` (one
+  policy decision), ``sched.dispatch``, ``sched.nap`` (a sleep waiting
+  for an arrival or a backoff);
+* executor (``serving/engine.py``): ``exec.submit`` (prompt build,
+  tokenization, engine submit of one subtask);
+* pool (``serving/pool.py``): ``pool.step`` (one pass, the join on the
+  replica workers included);
+* engine (``serving/engine.py``): ``engine.step``, ``engine.admit``,
+  ``engine.prefill_launch``, ``engine.decode_launch``, ``engine.sync``
+  (the blocking device-to-host read of sampled ids).
+"""
+from __future__ import annotations
+
+import jax
+
+PREFIX = "hf."
+
+
+def span(name: str, **ids) -> jax.profiler.TraceAnnotation:
+    """A host span ``hf.<name>``, used as ``with span(...):``."""
+    return jax.profiler.TraceAnnotation(PREFIX + name, **ids)
