@@ -94,10 +94,6 @@ class ModelConfig:
     # MoE dispatch implementation: "gather" (sorted capacity dispatch,
     # GSPMD-global) or "ep" (shard_map expert parallelism)
     moe_impl: str = "gather"
-    # decode: thread the KV cache through the layer scan as a carry with
-    # in-place dynamic-update-slice instead of xs/ys double buffering
-    # (kills the full-cache copy per decode step)
-    carry_cache: bool = False
 
     def __post_init__(self):
         if self.family not in ("dense", "moe", "ssm", "hybrid", "vlm", "audio"):

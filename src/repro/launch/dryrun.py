@@ -218,7 +218,6 @@ def model_flops(cfg: ModelConfig, shape: InputShape) -> float:
 _OPTS = {
     "blocked_attn": dict(attention_block_q=512),
     "blocked_attn_2k": dict(attention_block_q=2048),
-    "carry_cache": dict(carry_cache=True),
     "shard_seq": dict(shard_attn_seq=True),
     "ep_moe": dict(moe_impl="ep"),
     "expand_kv": "EXPAND_KV",     # resolved per-config (needs mesh size)

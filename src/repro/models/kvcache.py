@@ -143,18 +143,26 @@ def copy_prefix(cache_k, cache_v, src_idx, dst_idx, length, width: int):
 
 
 def write_decode(cache_k, cache_v, k, v, pos, window: Optional[int]):
-    """Write one token at per-request absolute position ``pos`` [B]."""
-    import jax.numpy as jnp
-    M = cache_k.shape[1]
-    b = jnp.arange(cache_k.shape[0])
+    """Write one token per row at per-request absolute position ``pos`` [B].
+
+    cache [B, M, KV, hd]; k/v [B, 1, KV, hd]. A layer-stacked cache
+    [L, B, M, KV, hd] takes ``pos`` as ``(layer, pos)`` and is written at
+    ``(layer, row, slot)`` alone: the decode layer scan carries the stack,
+    so XLA scatters the B lines in place instead of slicing the layer out
+    and writing it back.
+    """
+    layer = ()
+    if isinstance(pos, tuple):
+        layer, pos = (pos[0],), pos[1]
+    M = cache_k.shape[-3]
     slot = pos % M if window is not None else jnp.minimum(pos, M - 1)
-    cache_k = cache_k.at[b, slot].set(k[:, 0])
-    cache_v = cache_v.at[b, slot].set(v[:, 0])
+    idx = layer + (jnp.arange(k.shape[0]), slot)
+    cache_k = cache_k.at[idx].set(k[:, 0], unique_indices=True)
+    cache_v = cache_v.at[idx].set(v[:, 0], unique_indices=True)
     return cache_k, cache_v
 
 
 def valid_len(pos, max_len: int, window: Optional[int]):
     """Number of valid cache slots after writing token at ``pos`` [B]."""
-    import jax.numpy as jnp
     n = pos + 1
     return jnp.minimum(n, max_len)

@@ -153,6 +153,29 @@ def attention(q, k, v, *, causal: bool, window: Optional[int] = None,
     return out
 
 
+def decode_attention(q, k, v, *, kv_len):
+    """One-query attention against K/V lines as stored, heads grouped.
+
+    q [B,1,H,hd]; k/v [B,M,KV,hd]; kv_len [B]. The math of
+    :func:`attention` with only the ``kv_len`` mask, but q is viewed as
+    [B, KV, H/KV, hd] and contracted against the stored KV heads, so the
+    cache is never repeated to H heads. Returns [B,1,H,hd].
+    """
+    b, _, h, hd = q.shape
+    m, kv = k.shape[1], k.shape[2]
+    qg = q.reshape(b, kv, h // kv, hd)
+    scale = 1.0 / math.sqrt(hd)
+    scores = jnp.einsum("bgrd,bmgd->bgrm", qg, k,
+                        preferred_element_type=jnp.float32) * scale
+    mask = (jnp.arange(m)[None, :] < kv_len[:, None])[:, None, None, :]
+    scores = jnp.where(mask, scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1)
+    # a row with nothing in its cache would be NaN; zero it, as attention does
+    probs = jnp.where(jnp.any(mask, axis=-1, keepdims=True), probs, 0.0)
+    out = jnp.einsum("bgrm,bmgd->bgrd", probs.astype(v.dtype), v)
+    return out.reshape(b, 1, h, hd)
+
+
 def ragged_prefill_attention(q, k, v, *, pos0, take=None,
                              window: Optional[int] = None):
     """Reference twin of ``kernels.ops.ragged_prefill_attention``.
@@ -300,6 +323,9 @@ def _dispatch_attention(q, k, v, *, causal, window, q_offset=0, kv_len=None,
     if kd.use_pallas() and q.shape[1] == 1 and kv_len is not None:
         from repro.kernels import ops as kops
         return kops.decode_attention(q, k, v, kv_len=kv_len, window=window)
+    if q.shape[1] == 1 and kv_len is not None and not causal \
+            and window is None:
+        return decode_attention(q, k, v, kv_len=kv_len)
     return attention(q, k, v, causal=causal, window=window,
                      q_offset=q_offset, kv_len=kv_len)
 

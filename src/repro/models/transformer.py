@@ -98,19 +98,25 @@ def decoder_layer_prefill(p, cfg, h, ck, cv, *, window=None):
     return h + y, ck, cv
 
 
-def decoder_layer_decode(p, cfg, h, ck, cv, pos, *, window=None):
-    """One-token layer step. h [B,1,D]; pos [B] absolute position."""
+def decoder_layer_decode(p, cfg, h, ck, cv, layer, pos, *, window=None):
+    """One-token step of layer ``layer``. h [B,1,D]; pos [B] absolute
+    position; ck/cv the whole layer-stacked cache [L,B,M,KV,hd].
+
+    The new K/V lines are written into the stack in place and attention
+    reads the layer's lines as stored, with no copy of the layer."""
     hn = L.rms_norm(p["ln1"], h, cfg.norm_eps)
     q, k, v = L.attn_qkv(p["attn"], cfg, hn, positions=pos[:, None])
     ck, cv = KV.write_decode(ck, cv,
                              KV.expand_kv_for_cache(cfg, k).astype(ck.dtype),
                              KV.expand_kv_for_cache(cfg, v).astype(cv.dtype),
-                             pos, window)
-    kvl = KV.valid_len(pos, ck.shape[1], window)
+                             (layer, pos), window)
+    kvl = KV.valid_len(pos, ck.shape[2], window)
+    ck_l = jax.lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
+    cv_l = jax.lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
     # window=None here on purpose: rolling caches bound M to the window
     # and kv_len tracks validity. Under use_pallas() this is the batched
     # decode kernel — all slots/heads in one launch.
-    out = L._dispatch_attention(q, ck.astype(q.dtype), cv.astype(q.dtype),
+    out = L._dispatch_attention(q, ck_l.astype(q.dtype), cv_l.astype(q.dtype),
                                 causal=False, window=None, kv_len=kvl)
     b = h.shape[0]
     h = h + L.dense(p["attn"]["wo"], out.reshape(b, 1, cfg.q_dim))
@@ -272,36 +278,23 @@ def decoder_prefill_chunk(p, cfg, tokens, cache, slot_idx, pos0, take,
 
 
 def decoder_decode(p, cfg, token, pos, cache):
-    """token [B,1]; pos [B]. Returns (logits [B,1,V], cache)."""
+    """token [B,1]; pos [B]. Returns (logits [B,1,V], cache).
+
+    The layer scan carries the whole cache: each layer scatters its B new
+    lines at (layer, row, slot) of the donated pool, so a step writes
+    B x L lines and copies no layer and no cache."""
     h = L.embed(p["embed"], token)
     window = cfg.decode_window()
 
-    if cfg.carry_cache:
-        # §Perf: cache rides in the scan carry; the per-layer update is a
-        # dynamic-update-slice that XLA performs in place inside the while
-        # loop (the xs/ys form below double-buffers the ENTIRE cache every
-        # decode step — ~2x cache bytes of avoidable HBM traffic).
-        def body(carry, p_l):
-            h, ck_all, cv_all, li = carry
-            ck = jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False)
-            cv = jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False)
-            h, ck, cv = decoder_layer_decode(p_l, cfg, h, ck, cv, pos,
-                                             window=window)
-            ck_all = jax.lax.dynamic_update_index_in_dim(ck_all, ck, li, 0)
-            cv_all = jax.lax.dynamic_update_index_in_dim(cv_all, cv, li, 0)
-            return (h, ck_all, cv_all, li + 1), None
+    def body(carry, xs):
+        h, ck, cv = carry
+        p_l, layer = xs
+        h, ck, cv = decoder_layer_decode(p_l, cfg, h, ck, cv, layer, pos,
+                                         window=window)
+        return (h, ck, cv), None
 
-        (h, ck, cv, _), _ = layer_scan(
-            body, (h, cache["k"], cache["v"], jnp.int32(0)), p["layers"])
-    else:
-        def body(h, xs):
-            p_l, ck, cv = xs
-            h, ck, cv = decoder_layer_decode(p_l, cfg, h, ck, cv, pos,
-                                             window=window)
-            return h, (ck, cv)
-
-        h, (ck, cv) = layer_scan(body, h,
-                                 (p["layers"], cache["k"], cache["v"]))
+    (h, ck, cv), _ = layer_scan(body, (h, cache["k"], cache["v"]),
+                                (p["layers"], jnp.arange(cfg.n_layers)))
     h = L.rms_norm(p["final_norm"], h, cfg.norm_eps)
     return _logits(p, cfg, h), {"k": ck, "v": cv}
 

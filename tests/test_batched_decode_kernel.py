@@ -56,6 +56,29 @@ def test_batched_decode_sweep(case, dtype):
                                atol=_tol(dtype), rtol=_tol(dtype))
 
 
+@pytest.mark.parametrize("case", [c[:5] for c in BATCHED_DECODE_CASES
+                                  if c[5] is None])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_grouped_xla_decode_matches_ref(case, dtype):
+    """The XLA decode read (q grouped over the stored KV heads, no head
+    repeat) against the reference over repeated heads; a row with an
+    empty cache comes back as zeros."""
+    from repro.models import layers as L
+
+    B, M, H, KV, hd = case
+    seed = zlib.crc32(repr(case).encode())
+    q, k, v, kv_len = _inputs(B, M, H, KV, hd, seed=seed, dtype=dtype)
+    if B > 1:
+        kv_len = kv_len.at[0].set(0)
+    out = L.decode_attention(q, k, v, kv_len=kv_len)
+    want = ref.decode_attention_ref(q, k, v, kv_len)
+    assert out.dtype == want.dtype
+    assert B == 1 or (np.asarray(out[0]) == 0).all()
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=_tol(dtype), rtol=_tol(dtype))
+
+
 def test_batched_decode_kv_len_zero_rows_are_zero():
     """Slots with nothing in their cache skip every KV block and emit
     exact zeros (the safe-denominator finish), while live rows in the

@@ -12,6 +12,7 @@ The topology is described inside the module fixture — never at import —
 because only one process at a time may load the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,3 +120,34 @@ def test_serving_step_compiles_at_published_widths(one_chip, monkeypatch,
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < HBM_BYTES, (step, used)
+
+
+def test_decode_step_touches_the_kv_pool_in_place(one_chip):
+    """The XLA decode program (the engine's default backend) writes each
+    token into the donated pool and reads the stored KV heads grouped: its
+    temporaries hold no copy of the pool, no op repeats K/V to the query
+    heads, and nothing outside the layer loop rewrites a whole cache."""
+    slots = 2
+    cfg = get_config(ARCH)
+    bf = jnp.bfloat16
+    params = _placed(one_chip, jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0), dtype=bf)))
+    cache = _placed(one_chip, M.init_cache_specs(cfg, slots, MAX_LEN, bf))
+    key = _placed(one_chip, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    rows = _on(one_chip, (slots,), jnp.int32)
+    with dispatch.pallas_enabled(False):
+        decode_step, _ = _jit_steps(cfg, MAX_LEN, False)
+        compiled = decode_step.lower(
+            params, _on(one_chip, (slots, 1), jnp.int32), rows, cache, key,
+            _on(one_chip, (slots,), jnp.float32), rows).compile()
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool / 100, (temp, pool)
+    hlo = compiled.as_text()
+    assert f"[{slots},{MAX_LEN},{KV},{H // KV},{HD}]" not in hlo
+    whole = f"bf16[{cfg.n_layers},{slots},{MAX_LEN},{KV},{HD}]"
+    entry = hlo[hlo.index("\nENTRY"):]
+    made = {m.group(1) for m in re.finditer(
+        re.escape(whole) + r"\S* ([\w-]+)\(", entry)}
+    assert made <= {"parameter", "get-tuple-element", "tuple", "bitcast"}, \
+        made
