@@ -5,10 +5,12 @@ the accelerator and prints one JSON line::
 
     python3 -m chipbench.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-Everything a cell needs is data found by name: ``configs/<config>.json``
-(the model as run, with its published reference), ``traffic/<mix>.json``
-(the arrival process and length distributions), ``cells/<workload>.json``
-(the deployment and the correctness limit) and ``metrics/<metric>.py``
-(one reader per per-layer metric). ``BENCHMARK.json`` at the checkout
-root lists them.
+Everything a cell needs is found by name: ``configs/<config>.json`` (the
+model as run, with its published reference and its ``family``),
+``families/<family>.py`` (the family's weights, program layout and plain
+reference), ``traffic/<mix>.json`` (the arrival process and length
+distributions, and the entry ``drive_<entry>.py`` that serves them),
+``cells/<workload>.json`` (the deployment and the correctness limit) and
+``metrics/<metric>.py`` (one reader per per-layer metric).
+``BENCHMARK.json`` at the checkout root lists them.
 """

@@ -1,6 +1,6 @@
-"""What every entry shares: the served model from a config file, its
-weights from the seed, engines, warm-up through the public engine API,
-and the check of served tokens against the reference."""
+"""What every entry shares: engines, warm-up through the public engine
+API, and the check of served tokens against the reference of the
+configuration's family (``families/<family>.py``)."""
 from __future__ import annotations
 
 import gc
@@ -12,69 +12,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import reference, traffic, weights
+from chipbench import traffic
 from chipbench.recorder import Tracked
 
-# ModelConfig fields that must equal what the config file states
-_STATED = {"n_layers": "num_hidden_layers", "d_model": "hidden_size",
-           "n_heads": "num_attention_heads",
-           "n_kv_heads": "num_key_value_heads", "head_dim": "head_dim",
-           "d_ff": "intermediate_size", "vocab_size": "vocab_size",
-           "qkv_bias": "qkv_bias", "tie_embeddings": "tie_word_embeddings",
-           "rope_theta": "rope_theta", "norm_eps": "rms_norm_eps",
-           "dtype": "torch_dtype"}
-
-
-def model_config(model: dict):
-    """The program's ``ModelConfig`` for a config file, checked against
-    every size the file states."""
-    from repro.configs import get_config
-    cfg = get_config(model["arch_id"]).variant(**model.get("overrides", {}))
-    for f, key in _STATED.items():
-        got, want = getattr(cfg, f), model[key]
-        if got != want:
-            raise ValueError(f"{model['arch_id']}: the program's {f}={got!r} "
-                             f"departs from the stated {key}={want!r}")
-    if cfg.family != "dense" or cfg.decode_window() is not None:
-        raise ValueError(f"{model['arch_id']}: served config is not a "
-                         f"full-attention dense decoder as stated")
-    return cfg
-
-
-def program_params(cfg, sizes: weights.Sizes, words):
-    """The seeded canonical weights, laid out as the program's params
-    pytree (checked against ``init_params``'s shapes)."""
-    from repro.models import model as M
-    tmpl = jax.eval_shape(lambda: M.init_params(cfg, jax.random.PRNGKey(0)))
-    w = weights.canonical(sizes, words, jnp.dtype(cfg.dtype))
-    vp = tmpl["embed"]["w"].shape[0]
-
-    def rows(x):
-        return jnp.pad(x, ((0, vp - x.shape[0]), (0, 0)))
-
-    def lin(name):
-        d = {"w": w[name]}
-        b = "b" + name[1]
-        if name in ("wq", "wk", "wv") and b in w:
-            d["b"] = w[b]
-        return d
-
-    p = {"embed": {"w": rows(w["embed"])},
-         "layers": {"ln1": {"scale": w["ln1"]},
-                    "attn": {n: lin(n) for n in ("wq", "wk", "wv", "wo")},
-                    "ln2": {"scale": w["ln2"]},
-                    "mlp": {n: {"w": w[n]}
-                            for n in ("w_gate", "w_up", "w_down")}},
-         "final_norm": {"scale": w["final_norm"]}}
-    if "lm_head" in w:
-        p["lm_head"] = {"w": rows(w["lm_head"])}
-    want = jax.tree.map(lambda s: (s.shape, s.dtype), tmpl)
-    got = jax.tree.map(lambda a: (a.shape, a.dtype), p)
-    if want != got:
-        raise ValueError(f"weights do not match the program's parameter "
-                         f"layout for {cfg.arch_id}")
-    del w
-    return p
+# past the drain limit, the seconds a device step may still take before
+# the process is ended as hung
+HANG_S = 60.0
 
 
 def build_engine(cfg, params, dep: dict, slots: Optional[int] = None):
@@ -96,7 +39,9 @@ def warm_engine(engine, plan: List[dict], vocab: int, seed: int) -> int:
     tokens in the last slot and then, for each ``m`` below the slots,
     ``m`` requests that share its first ``n`` tokens, so that the
     prefix-seed copy runs for ``m`` slots at every width the prefix hits
-    reach. Other ids are random, so no other pass shares a prefix."""
+    reach. ``{"lengths": [n, ...]}`` is one pass that admits a request
+    of each length together. Other ids are random, so no other pass
+    shares a prefix."""
     rng = traffic.rng_for(seed, 99)
     passes = []
     for p in plan:
@@ -112,6 +57,9 @@ def warm_engine(engine, plan: List[dict], vocab: int, seed: int) -> int:
                         [head[:int(n)] + rng.integers(16, vocab, n_all - int(n)
                                                       ).tolist()
                          for _ in range(m)], p.get("new", 2)))
+            continue
+        if "lengths" in p:
+            passes.append(([int(n) for n in p["lengths"]], p.get("new", 2)))
             continue
         g0, g1 = p["g"]
         for g in range(int(g0), min(int(g1), engine.slots) + 1):
@@ -191,7 +139,7 @@ def _bucket(n: int, lo: int) -> int:
     return b
 
 
-def logit_gaps(sizes: weights.Sizes, w: dict, reqs: List[Served],
+def logit_gaps(family, sizes, w: dict, reqs: List[Served],
                modes=("f32",)) -> Dict[str, List[float]]:
     """Per request and mode, the widest gap by which a judged token's
     logit lies below the reference's best. In mode ``f32`` the judged
@@ -200,21 +148,21 @@ def logit_gaps(sizes: weights.Sizes, w: dict, reqs: List[Served],
     out: Dict[str, List[float]] = {m: [] for m in modes}
     for r in reqs:
         seq = r.ctx + r.out[:-1]
-        T = _bucket(len(seq), reference.Q_BLOCK)
+        T = _bucket(len(seq), family.Q_BLOCK)
         P = _bucket(len(r.out), 64)
         ids = np.zeros(T, np.int32)
         ids[:len(seq)] = seq
         want = np.zeros(P, np.int32)
         want[:len(r.out)] = len(r.ctx) - 1 + np.arange(len(r.out))
         ids, want = jnp.asarray(ids), jnp.asarray(want)
-        ref = reference.logits_at(sizes, w, ids, want, "f32")
+        ref = family.logits_at(sizes, w, ids, want, "f32")
         best = jnp.max(ref, -1)
         for mode in modes:
             if mode == "f32":
                 tok = jnp.asarray(np.pad(r.out, (0, P - len(r.out))))
             else:
-                tok = jnp.argmax(reference.logits_at(sizes, w, ids, want,
-                                                     mode), -1)
+                tok = jnp.argmax(family.logits_at(sizes, w, ids, want,
+                                                  mode), -1)
             at = jnp.take_along_axis(ref, tok[:, None].astype(jnp.int32),
                                      -1)[:, 0]
             out[mode].append(float(np.asarray(best - at)[:len(r.out)].max()))
@@ -241,21 +189,22 @@ class Check:
             self.notes.append(note)
 
 
-def check_served(check: Check, model: dict, seeds: List,
+def check_served(check: Check, family, model: dict, seeds: List,
                  reqs: List[Served], limit: float,
                  controls: tuple = ()) -> Dict[str, object]:
     """Draw each weight stream again and compare its sampled requests
-    with the reference; each precision in ``controls`` is read too (the
-    limit-setting tool asks for them, benchmark runs do not)."""
-    sizes = weights.Sizes.of(model)
+    with the family's reference; each precision in ``controls`` is read
+    too (the limit-setting tool asks for them, benchmark runs do not)."""
+    sizes = family.sizes(model)
     t0 = time.perf_counter()
     gaps: Dict[str, List[float]] = {m: [] for m in ("f32",) + tuple(controls)}
     for stream, words in enumerate(seeds):
         mine = [r for r in reqs if r.stream == stream]
         if not mine:
             continue
-        w = weights.canonical(sizes, words, jnp.dtype(model["torch_dtype"]))
-        for mode, v in logit_gaps(sizes, w, mine, tuple(gaps)).items():
+        w = family.canonical(sizes, words, jnp.dtype(model["torch_dtype"]))
+        for mode, v in logit_gaps(family, sizes, w, mine,
+                                  tuple(gaps)).items():
             gaps[mode].extend(v)
         del w
         free_device()

@@ -19,9 +19,6 @@ from chipbench.recorder import Recorder
 
 RECOVERY = ("retries", "degraded", "exec_faults", "timeouts",
             "cloud_deaths", "cloud_failovers")
-# past the drain limit, the seconds a device step may still take before
-# the process is ended as hung
-HANG_S = 60.0
 
 
 class DrainLimit(Exception):
@@ -39,6 +36,19 @@ def _stop_at(executor, deadline: float) -> None:
         return pump()
 
     executor.pump = pump_or_stop
+
+
+def shapes(cell) -> list:
+    """The edge engine's and the cloud replicas' shapes for
+    ``rehearse.py``: (label, slots, max_len, chunk, replicas, g,
+    kv_width), the widest prefill taken as every slot at once against the
+    whole cache."""
+    d = cell.cell["deployment"]
+    max_len, chunk = int(d["max_len"]), int(d["prefill_chunk"])
+    return [(name, int(d[f"{name}_slots"]), max_len, chunk, copies,
+             int(d[f"{name}_slots"]), max_len)
+            for name, copies in (("edge", 1), ("cloud",
+                                               int(d["cloud_replicas"])))]
 
 
 def _dag_ok(res, sched) -> str:
@@ -71,12 +81,14 @@ def run(ctx) -> dict:
 
     cell, seed, seconds = ctx.cell, ctx.seed, ctx.seconds
     mix, dep, model = cell.traffic, cell.cell["deployment"], cell.model
-    cfg = C.model_config(model)
-    sizes = weights.Sizes.of(model)
+    fam = cell.family
+    cfg = fam.served_config(model)
+    sizes = fam.sizes(model)
     words = [weights.seed_words(seed, 0), weights.seed_words(seed, 1)]
-    edge_engine = C.build_engine(cfg, C.program_params(cfg, sizes, words[0]),
+    edge_engine = C.build_engine(cfg, fam.program_params(cfg, sizes, words[0]),
                                  dep, slots=dep["edge_slots"])
-    cloud_engine = C.build_engine(cfg, C.program_params(cfg, sizes, words[1]),
+    cloud_engine = C.build_engine(cfg, fam.program_params(cfg, sizes,
+                                                          words[1]),
                                   dep, slots=dep["cloud_slots"])
     wm = WorldModel()
     edge = JAXExecutor(edge_engine, wm, cloud=False,
@@ -136,7 +148,7 @@ def run(ctx) -> dict:
     for ex in (runtime.edge, runtime.cloud):
         _stop_at(ex, deadline)
     done = threading.Event()
-    ctx.watchdog(done, deadline + HANG_S)
+    ctx.watchdog(done, deadline + C.HANG_S)
     try:
         report = runtime.serve(arrivals=times)
     except DrainLimit:
@@ -198,7 +210,7 @@ def run(ctx) -> dict:
     del edge_engine, cloud_engine, edge, cloud, runtime, pool, replicas
     del engines, logs, report, scheds
     C.free_device()
-    result["reference"] = C.check_served(ctx.check, model, words, pick,
+    result["reference"] = C.check_served(ctx.check, fam, model, words, pick,
                                          float(corr["limit"]),
                                          controls=ctx.controls)
     return result

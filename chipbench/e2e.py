@@ -57,6 +57,14 @@ def itl_p99_ms(r: Readings) -> Optional[float]:
     return 1000.0 * float(np.percentile(g, 99)) if g else None
 
 
+def tokens_per_s(r: Readings) -> Optional[float]:
+    """Output tokens that became visible inside the window, every
+    request's, over the window's length."""
+    lo, hi = r.window
+    n = r.recorder.tokens_in(lo, hi)
+    return n / (hi - lo) if n else None
+
+
 def gap_quantiles(r: Readings) -> dict:
     """Token gaps of the window at a few quantiles, in ms, for the log."""
     g = np.asarray(r.recorder.token_gaps(*r.window), np.float64)
@@ -66,7 +74,8 @@ def gap_quantiles(r: Readings) -> dict:
             for q in (50, 90, 95, 99)} | {"n": int(g.size)}
 
 
-METRICS = {f.__name__: f for f in (setup_s, query_mean_s, itl_p99_ms)}
+METRICS = {f.__name__: f for f in (setup_s, query_mean_s, itl_p99_ms,
+                                   tokens_per_s)}
 
 
 def compute(name: str, r: Readings) -> Optional[float]:

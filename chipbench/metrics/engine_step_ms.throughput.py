@@ -1,0 +1,7 @@
+"""``engine_step_ms``, read by the same reader, in the cells judged on
+``tokens_per_s``."""
+from pathlib import Path
+
+from chipbench.registry import load_reader
+
+read = load_reader("engine_step_ms", Path(__file__).resolve().parent)

@@ -1,8 +1,9 @@
 """Find a cell's files by the names in ``BENCHMARK.json``.
 
-A later change adds a cell or a per-layer metric by adding files under
-``configs/``, ``traffic/``, ``cells/`` and ``metrics/`` and entries in
-``BENCHMARK.json``; nothing here names a cell, a mix or a metric.
+A later change adds a cell, a per-layer metric or a model family by
+adding files under ``configs/``, ``traffic/``, ``cells/``, ``metrics/``
+and ``families/`` and entries in ``BENCHMARK.json``; nothing here names a
+cell, a mix, a metric or a family.
 """
 from __future__ import annotations
 
@@ -14,6 +15,10 @@ from typing import Callable, Dict, List, Optional
 
 PKG = Path(__file__).resolve().parent
 ROOT = PKG.parent
+# what every families/<family>.py exposes (families/dense.py says what
+# each one is)
+FAMILY_API = ("sizes", "canonical", "served_config", "program_params",
+              "logits_at", "Q_BLOCK")
 
 
 def _load_json(path: Path) -> dict:
@@ -47,6 +52,7 @@ class Cell:
     model: dict              # configs/<config>.json
     traffic: dict            # traffic/<mix>.json
     cell: dict               # cells/<workload>.json
+    family: object = None    # families/<model["family"]>.py
     end_to_end: List[Metric] = field(default_factory=list)
     per_layer: List[Metric] = field(default_factory=list)
 
@@ -55,17 +61,28 @@ def load_benchmark(root: Path = ROOT) -> dict:
     return _load_json(root / "BENCHMARK.json")
 
 
-def load_reader(name: str, metrics_dir: Path = PKG / "metrics") -> Callable:
-    """The ``read(ctx)`` function of ``metrics/<name>.py``."""
-    path = metrics_dir / f"{name}.py"
+def _load_module(kind: str, name: str, path: Path):
     spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    if spec is None or spec.loader is None:
-        raise FileNotFoundError(f"no reader for per-layer metric {name!r}: "
-                                f"{path}")
+        f"chipbench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
+    if spec is None or spec.loader is None or not path.exists():
+        raise FileNotFoundError(f"no {kind} module for {name!r}: {path}")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(name: str, metrics_dir: Path = PKG / "metrics") -> Callable:
+    """The ``read(ctx)`` function of ``metrics/<name>.py``."""
+    return _load_module("metric", name, metrics_dir / f"{name}.py").read
+
+
+def load_family(name: str, families_dir: Path = PKG / "families"):
+    """The module ``families/<name>.py``, checked for ``FAMILY_API``."""
+    mod = _load_module("family", name, families_dir / f"{name}.py")
+    missing = [a for a in FAMILY_API if not hasattr(mod, a)]
+    if missing:
+        raise AttributeError(f"family {name!r} lacks {missing}")
+    return mod
 
 
 def _metric(entry: dict) -> Metric:
@@ -90,6 +107,7 @@ def load_cell(workload: str, root: Path = ROOT,
                 model=_load_json(root / conf["file"]),
                 traffic=_load_json(pkg / "traffic" / f"{w['traffic']}.json"),
                 cell=_load_json(pkg / "cells" / f"{workload}.json"))
+    cell.family = load_family(cell.model["family"], pkg / "families")
     cell.end_to_end = [m for m in map(_metric, bench["end_to_end"])
                        if m.applies_to(workload)]
     for entry in bench["per_layer"]:

@@ -5,14 +5,15 @@ largest step's temporaries fit on one chip.
     JAX_PLATFORMS=cpu python3 -m chipbench.rehearse [--write] [workload ...]
 
 For every engine shape of a cell it compiles the engine's decode program
-and its widest prefill program (every slot prefilling one full chunk
-against the whole cache), prints ``memory_analysis()`` and, with
+and the widest prefill program the cell launches (as its entry's
+``shapes(cell)`` says), prints ``memory_analysis()`` and, with
 ``--write``, stores the reckoning under ``sizing`` in the cell's file.
 A compile that passes here is not a chip run.
 """
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import os
 import sys
@@ -23,10 +24,15 @@ GB = 1e9
 
 
 def shapes_of(cell) -> list:
-    """(label, slots, max_len, chunk) of each engine shape in a cell."""
-    d = cell.cell["deployment"]
-    return [("edge", d["edge_slots"], d["max_len"], d["prefill_chunk"]),
-            ("cloud", d["cloud_slots"], d["max_len"], d["prefill_chunk"])]
+    """The engine shapes of a cell, as its entry ``drive_<entry>.py``
+    gives them in ``shapes(cell)``: (label, slots, max_len, chunk,
+    replicas, g, kv_width) each, where ``g`` and ``kv_width`` are the
+    rows and cache lines of the widest prefill the cell launches. Each
+    shape has weights of its own; replicas of one shape share them and
+    hold a KV pool each."""
+    entry = importlib.import_module(
+        f"chipbench.drive_{cell.traffic['entry']}")
+    return entry.shapes(cell)
 
 
 def rehearse(cell, topo) -> dict:
@@ -35,10 +41,9 @@ def rehearse(cell, topo) -> dict:
     from jax.sharding import SingleDeviceSharding
     from repro.models import model as M
     from repro.serving.engine import _jit_steps
-    from chipbench.drive_common import model_config
 
     one = SingleDeviceSharding(topo.devices[0])
-    cfg = model_config(cell.model)
+    cfg = cell.family.served_config(cell.model)
     dt = jnp.dtype(cfg.dtype)
 
     def sds(tree):
@@ -52,7 +57,7 @@ def rehearse(cell, topo) -> dict:
     i32, f32 = jnp.int32, jnp.float32
     key = sds(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
     out = {"weights_bytes": int(wbytes), "engines": []}
-    for label, slots, max_len, chunk in shapes_of(cell):
+    for label, slots, max_len, chunk, copies, g, kv in shapes_of(cell):
         cache = sds(jax.eval_shape(
             lambda: M.init_cache(cfg, slots, max_len, dtype=dt)))
         cbytes = sum(x.size * x.dtype.itemsize
@@ -65,11 +70,11 @@ def rehearse(cell, topo) -> dict:
         dec = decode_fn.lower(params, a((slots, 1), i32), a((slots,), i32),
                               cache, key, a((slots,), f32),
                               a((slots,), i32)).compile()
-        g = slots
         pre = prefill_fn.lower(params, a((g, chunk), i32), a((g,), i32),
                                a((g,), i32), a((g,), i32), a((slots,), i32),
-                               cache, key, a((g,), f32), max_len).compile()
+                               cache, key, a((g,), f32), kv).compile()
         row = {"engine": label, "slots": slots, "max_len": max_len,
+               "replicas": copies, "prefill_rows": g, "prefill_kv": kv,
                "kv_pool_bytes": int(cbytes)}
         for name, c in (("decode", dec), ("prefill", pre)):
             m = c.memory_analysis()
@@ -78,12 +83,12 @@ def rehearse(cell, topo) -> dict:
                          "temp_bytes": int(m.temp_size_in_bytes),
                          "alias_bytes": int(m.alias_size_in_bytes)}
         out["engines"].append(row)
-    pools = sum(r["kv_pool_bytes"] for r in out["engines"])
-    n_weights = 2 if len(out["engines"]) > 1 else 1
+    pools = sum(r["kv_pool_bytes"] * r["replicas"] for r in out["engines"])
+    n_weights = len(out["engines"])
     worst_temp = max(r[k]["temp_bytes"] for r in out["engines"]
                      for k in ("decode", "prefill"))
     out["reckoning_bytes"] = int(n_weights * wbytes + pools + worst_temp)
-    out["reckoning"] = (f"{n_weights} x weights + KV pools + the largest "
+    out["reckoning"] = (f"{n_weights} x weights + every KV pool + the largest "
                         f"step's temporaries = "
                         f"{out['reckoning_bytes'] / GB:.2f} GB of the chip's 16")
     return out

@@ -5,9 +5,11 @@
 Each rate runs in a copy of the benchmark under a temporary directory
 whose traffic file carries that rate (the program is linked, not copied,
 so the compile cache is shared). Prints one JSON line per rate with the
-run's end-to-end metrics, its failures and how long the fleet took to
-drain after the window closed. The rate a cell runs at is then written
-into its traffic file by hand, with the sweep recorded in ``PERF.md``.
+run's end-to-end metrics, its failures, how long the system took to
+drain after the window closed and the entry's log of the window (for the
+engine entry, the requests still queued at the close). The rate a cell
+runs at is then written into its traffic file by hand, with the sweep
+recorded in ``PERF.md``.
 """
 from __future__ import annotations
 
@@ -54,6 +56,7 @@ def run_at(workload: str, rate: float, seed: int, seconds: float,
                         for k, v in out.get("metrics", {}).items()},
             "drain_s": info.get("drain_s"),
             "compiles_in_window": info.get("compiles_in_window"),
+            "detail": info.get("detail"), "setup": info.get("setup"),
             "stderr_tail": p.stderr[-600:] if p.returncode else ""}
 
 

@@ -85,6 +85,22 @@ def test_readers_without_a_trace_return_nothing():
         assert registry.load_reader(name)(r) is None
 
 
+@pytest.mark.parametrize("name", ["decode_device_ms", "idle_share"])
+def test_throughput_reader_reads_as_its_metric(name):
+    """``<m>.throughput`` reads what ``<m>`` reads, with a trace and
+    without one."""
+    red = SimpleNamespace(program_time=lambda p: (0.5, 2), busy_s=3.0,
+                          window_s=4.0)
+    for red_ in (red, None):
+        r = _readings({"window": (0, 10), "recorder": _recorder([])},
+                      red=red_)
+        assert registry.load_reader(f"{name}.throughput")(r) == \
+            registry.load_reader(name)(r)
+    assert registry.load_reader(f"{name}.throughput")(
+        _readings({"window": (0, 1), "recorder": _recorder([])},
+                  red=red)) is not None
+
+
 @pytest.mark.parametrize("value,ok", [(0.1, True), (0.3, False),
                                       (float("nan"), False)])
 def test_check_compares_a_number_with_its_limit(value, ok):
@@ -118,3 +134,33 @@ def test_served_of_takes_finished_requests_with_their_engine():
     assert len(got) == 1
     assert (got[0].ctx, got[0].out) == ([1, 99, 3], [5, 6])
     assert (got[0].stream, got[0].engine) == (1, "cloud0")
+
+
+def test_mfu_decode_counts_each_decode_row_at_its_context():
+    """Two requests decode in the traced second: their rows' operations
+    per step over the decode program's time per execution."""
+    fam = registry.load_family("dense")
+    model = json.loads((registry.PKG / "configs" / "internlm2-1.8b.json")
+                       .read_text())
+    sz = fam.sizes(model)
+    a = Tracked(SimpleNamespace(prompt_ids=[1] * 10, max_new_tokens=8),
+                times=[0.5, 1.2, 1.4, 2.5])
+    b = Tracked(SimpleNamespace(prompt_ids=[1] * 30, max_new_tokens=8),
+                times=[1.2, 1.4])
+    red = SimpleNamespace(program_time=lambda p: (0.004, 2)
+                          if p == "jit_decode_fn" else (0.0, 0))
+    r = e2e.Readings(
+        cell=SimpleNamespace(family=fam,
+                             cell={"deployment": {"max_len": 4096}}),
+        result={"window": (0, 3), "recorder": _recorder([a, b]),
+                "sizes": sz},
+        ctx=SimpleNamespace(trace_host=(1.0, 2.0)), t_start=0.0,
+        peaks={"flops_bf16": 197e12}, reduced=red)
+    # a's tokens 1 and 2 attend 11 and 12 lines; b's token 0 is prefill's
+    # and its token 1 attends 31; two steps, 2 ms each
+    flops = sum(fam.decode_flops(sz, n) for n in (11, 12, 31))
+    want = 100.0 * (flops / 2) / 0.002 / 197e12
+    assert registry.load_reader("mfu.decode")(r) == pytest.approx(want)
+    assert 0.5 < want < 2.0
+    r.ctx = SimpleNamespace(trace_host=None)
+    assert registry.load_reader("mfu.decode")(r) is None

@@ -158,12 +158,13 @@ def test_dispatch_wait_reader():
 
 
 def test_traced_tiny_fleet_run_reads_every_phase(tmp_path):
-    """The trace starts 1.5 s into the window, past the step programs
-    that the CPU compiles on first use (a span open when the trace
-    starts is not in it)."""
+    """The trace starts 3 s into the window, past the step programs
+    that the CPU compiles on first use, with room for a CPU shared with
+    other test processes (a span open when the trace starts is not in
+    it)."""
     from repro.serving import engine
     model = tiny.small_model("qwen2-1.5b")
-    cell = dict(tiny.FLEET_CELL, trace={"offset_s": 1.5, "seconds": 1.5})
+    cell = dict(tiny.FLEET_CELL, trace={"offset_s": 3.0, "seconds": 1.5})
     tiny.write(tmp_path, [("tiny.fleet", "tiny-qwen2", model, "fmix",
                            tiny.FLEET_MIX, cell)],
                metrics=("dispatch_wait_mean_s",))
@@ -171,7 +172,7 @@ def test_traced_tiny_fleet_run_reads_every_phase(tmp_path):
     _, peaks = tiny.cpu_device()
     engine._jit_steps.cache_clear()
     try:
-        out = P.run_phases(cell, 2**31 + 5, 4.0, peaks)
+        out = P.run_phases(cell, 2**31 + 5, 6.0, peaks)
     finally:
         engine._jit_steps.cache_clear()
     assert out["correct"] is True

@@ -1,6 +1,6 @@
 """A throwaway benchmark at CPU sizes, written into a temporary directory:
-``BENCHMARK.json`` plus config, traffic, cell and metric files of its own,
-found by the registry exactly as the real ones are."""
+``BENCHMARK.json`` plus config, traffic, cell, metric and family files of
+its own, found by the registry exactly as the real ones are."""
 from __future__ import annotations
 
 import json
@@ -48,24 +48,49 @@ FLEET_CELL = {
 }
 
 
+ENGINE_MIX = {
+    "entry": "engine", "arrival": "poisson", "rate": 6.0, "burst": 4,
+    "preroll_s": 0.5, "drain_s": 120.0, "trace_seed": 0,
+    "prompt": {"dist": "lognormal", "median": 24, "sigma": 0.5, "min": 8,
+               "max": 64},
+    "answer": {"dist": "lognormal", "median": 16, "sigma": 0.6, "min": 4,
+               "max": 48},
+}
+ENGINE_CELL = {
+    "deployment": {"slots": 4, "max_len": 128, "prefill_chunk": 16,
+                   "prefix_reuse": True},
+    "warm": [],
+    "trace": {"offset_s": 0.2, "seconds": 0.5},
+    "correct": {"limit": 0.02, "sample_tokens": 60, "max_requests": 6},
+}
+
+
 def write(root: Path, cells, metrics=("engine_step_ms",
                                       "compiles_in_window")) -> Path:
     """Lay out a benchmark under ``root``; ``cells`` is a list of
     (workload, config, model dict, traffic name, mix dict, cell dict).
-    Per-layer metric readers are copied from the real ones by name."""
+    Per-layer metric readers, and each model's family where ``root``
+    holds none of that name yet, are copied from the real ones."""
     pkg = root / "chipbench"
-    for sub in ("configs", "traffic", "cells", "metrics"):
+    for sub in ("configs", "traffic", "cells", "metrics", "families"):
         (pkg / sub).mkdir(parents=True, exist_ok=True)
     bench = {"command": ["python3", "-m", "chipbench.run"],
              "paths": ["chipbench"], "run_seconds": 2, "configs": [],
              "workloads": [], "end_to_end": [
                  {"name": "itl_p99_ms", "unit": "ms", "better": "lower",
                   "bound": 0.1, "source": "host_clock"},
+                 {"name": "tokens_per_s", "unit": "tokens/s",
+                  "better": "higher", "bound": 0.1, "source": "host_clock",
+                  "workloads": [c[0] for c in cells
+                                if c[4]["entry"] == "engine"]},
                  {"name": "setup_s", "unit": "s", "better": "lower",
                   "bound": 0.25, "source": "host_clock"}],
              "per_layer": []}
     seen = set()
     for workload, config, model, mix_name, mix, cell in cells:
+        fam = pkg / "families" / f"{model['family']}.py"
+        if not fam.exists():
+            shutil.copy(registry.PKG / "families" / fam.name, fam)
         if config not in seen:
             seen.add(config)
             f = f"chipbench/configs/{config}.json"

@@ -39,6 +39,9 @@ class Sizes:
     tied: bool
     rope_theta: float
     norm_eps: float
+    # token ids whose row of the output head is zero, so that their logit
+    # is 0 and random weights never make them the greedy token
+    silent: tuple = ()
 
     @classmethod
     def of(cls, model: dict) -> "Sizes":
@@ -53,7 +56,9 @@ class Sizes:
                    qkv_bias=bool(model["qkv_bias"]),
                    tied=bool(model["tie_word_embeddings"]),
                    rope_theta=float(model["rope_theta"]),
-                   norm_eps=float(model["rms_norm_eps"]))
+                   norm_eps=float(model["rms_norm_eps"]),
+                   silent=tuple(int(i) for i in
+                                model.get("silent_head_rows", ())))
 
     def shapes(self) -> dict:
         L, D, F, V = self.layers, self.d_model, self.d_ff, self.vocab
@@ -98,6 +103,9 @@ def draw(sizes: Sizes, words, dtype) -> dict:
         mean, std = _scale(name, shape)
         x = jax.random.normal(jax.random.fold_in(key, j), shape, jnp.float32)
         out[name] = (mean + std * x).astype(dtype)
+    if sizes.silent:
+        head = "embed" if sizes.tied else "lm_head"
+        out[head] = out[head].at[jnp.asarray(sizes.silent)].set(0)
     return out
 
 
