@@ -25,6 +25,7 @@ _MODULES = {
     "internlm2-1.8b": "repro.configs.internlm2_1_8b",
     "qwen3-4b": "repro.configs.qwen3_4b",
     "qwen2-1.5b": "repro.configs.qwen2_1_5b",
+    "deepseek-v2-lite": "repro.configs.deepseek_v2_lite",
 }
 
 ARCH_IDS: List[str] = list(_MODULES)

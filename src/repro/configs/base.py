@@ -52,6 +52,29 @@ class ModelConfig:
     n_shared_experts: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    norm_topk_prob: bool = True         # renormalise the top-k gates
+    routed_scaling: float = 1.0         # scale on the routed experts' sum
+    # the routed experts this chip holds (expert parallelism): experts
+    # [expert_offset, expert_offset + n_experts_held) of n_experts; the
+    # router still scores all n_experts. None holds every expert.
+    n_experts_held: Optional[int] = None
+    expert_offset: int = 0
+    # leading dense layers before the expert layers, with their own width
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
+
+    # latent attention (MLA, DeepSeek-V2); kv_lora_rank > 0 turns it on
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling; factor 0 leaves rope plain
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # SSM / hybrid
     ssm_state: int = 0          # Mamba2 N
@@ -102,6 +125,10 @@ class ModelConfig:
             raise ValueError("moe family requires n_experts/top_k")
         if self.family == "audio" and not self.is_encoder_decoder:
             raise ValueError("audio family must be encoder-decoder")
+        if self.first_k_dense and not self.is_mla:
+            raise ValueError("leading dense layers need the latent stack")
+        if self.experts_held + self.expert_offset > self.n_experts:
+            raise ValueError("held experts lie outside the router's range")
 
     # ---- derived ------------------------------------------------------
     @property
@@ -115,6 +142,15 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.resolved_head_dim
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_held(self) -> int:
+        return (self.n_experts if self.n_experts_held is None
+                else self.n_experts_held)
 
     @property
     def d_inner(self) -> int:
@@ -160,6 +196,11 @@ class ModelConfig:
             n_m = self.n_layers - n_s
             return emb + n_m * per_m + n_s * per_s
         attn = D * self.q_dim + 2 * D * self.kv_dim + self.q_dim * D
+        if self.is_mla:
+            H, C, R = self.n_heads, self.kv_lora_rank, self.qk_rope_head_dim
+            N, Vh = self.qk_nope_head_dim, self.v_head_dim
+            attn = (D * H * (N + R) + D * (C + R) + C + C * H * (N + Vh)
+                    + H * Vh * D)
         if self.family == "hybrid":
             di = self.d_inner
             N, H = self.ssm_state, self.n_ssm_heads
@@ -176,7 +217,9 @@ class ModelConfig:
         else:
             n_mlp = 3 if self.activation == "swiglu" else 2
             mlp = n_mlp * D * F
-        dec = self.n_layers * (attn + mlp)
+        k = self.first_k_dense
+        dec = self.n_layers * (attn + mlp) + k * (3 * D * self.dense_d_ff
+                                                  - mlp)
         enc = 0
         if self.is_encoder_decoder:
             cross = attn  # cross-attention block per decoder layer
@@ -214,6 +257,16 @@ class ModelConfig:
         )
         if self.n_experts:
             kw.update(n_experts=min(self.n_experts, 4), top_k=min(self.top_k, 2))
+            if self.n_experts_held is not None:
+                kw.update(n_experts_held=min(self.n_experts_held, 2),
+                          expert_offset=0)
+        if self.first_k_dense:
+            kw.update(first_k_dense=1, dense_d_ff=min(self.dense_d_ff, 512))
+        if self.is_mla:
+            kw.update(kv_lora_rank=min(self.kv_lora_rank, 64),
+                      qk_nope_head_dim=min(self.qk_nope_head_dim, 32),
+                      qk_rope_head_dim=min(self.qk_rope_head_dim, 16),
+                      v_head_dim=min(self.v_head_dim, 32))
         return replace(self, **kw)
 
     def variant(self, **kw) -> "ModelConfig":

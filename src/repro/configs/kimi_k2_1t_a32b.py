@@ -1,9 +1,11 @@
 """kimi-k2-1t-a32b — trillion-parameter MoE (paper-table cloud-scale executor).
 
-[arXiv:2501.kimi2] 61L d_model=7168 64H (GQA kv=8) d_ff=2048 (per expert)
-vocab=163840, MoE 384 experts top-8 (+1 shared expert). head_dim is set to
-128 (hardware-aligned MXU tile; 7168/64=112 would misalign the systolic
-array — noted in DESIGN.md as a TPU adaptation).
+[arXiv:2501.kimi2] 61L d_model=7168 64H d_ff=2048 (per expert)
+vocab=163840, MoE 384 experts top-8 (+1 shared expert). The published
+model uses latent attention (MLA, as deepseek-v2-lite does); this config
+stands it in with GQA attention (kv=8, head_dim 128) on the capacity-routed
+expert path, for training and dry runs only. It is not moved onto the
+repo's MLA stack.
 """
 from repro.configs.base import ModelConfig
 

@@ -58,6 +58,17 @@ def init_attn_cache(cfg, batch: int, max_len: int, n_layers: int,
     }
 
 
+def init_latent_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
+    """Latent-attention (MLA) cache: per layer and token one ``c_kv`` line
+    [kv_lora_rank] and one roped ``k_pe`` line [qk_rope_head_dim], shared
+    by every head, in place of per-head K and V."""
+    L = cfg.n_layers
+    return {
+        "ckv": jnp.zeros((L, batch, max_len, cfg.kv_lora_rank), dtype),
+        "kpe": jnp.zeros((L, batch, max_len, cfg.qk_rope_head_dim), dtype),
+    }
+
+
 def write_prefill(cache_k, cache_v, k, v, window: Optional[int]):
     """Single-layer prefill write. cache [B,M,KV,hd]; k/v [B,S,KV,hd].
 
@@ -79,7 +90,7 @@ def write_prefill(cache_k, cache_v, k, v, window: Optional[int]):
     return cache_k, cache_v
 
 
-def write_chunk(cache_k, cache_v, k, v, slot_idx, pos0, take):
+def write_chunk(cache_k, cache_v, k, v, slot_idx, pos0, take, layer=None):
     """Batched ragged chunk write into a slot-pooled cache.
 
     cache [B, M, KV, hd] (the engine's shared pool); k/v [G, S, KV, hd]
@@ -90,15 +101,19 @@ def write_chunk(cache_k, cache_v, k, v, slot_idx, pos0, take):
     updates a donated pool in place. Padded positions are routed out of
     bounds and dropped, so they can never corrupt lines a row already
     owns and the compiled program is one scatter regardless of G.
+    A layer-stacked cache [L, B, M, ...] takes ``layer`` and is written at
+    (layer, row, line) in place, with no copy of the layer. Trailing dims
+    are free, so latent lines [G, S, C] go the same way.
     """
-    M = cache_k.shape[1]
+    lead = () if layer is None else (layer,)
+    M = cache_k.shape[len(lead) + 1]
     G, S = k.shape[:2]
     assert S <= M, f"chunk width {S} exceeds cache lines {M}"
     cols = pos0[:, None] + jnp.arange(S)[None, :]            # [G, S]
     cols = jnp.where(jnp.arange(S)[None, :] < take[:, None], cols, M)
     rows = slot_idx[:, None]                                 # [G, 1]
-    cache_k = cache_k.at[rows, cols].set(k, mode="drop")
-    cache_v = cache_v.at[rows, cols].set(v, mode="drop")
+    cache_k = cache_k.at[lead + (rows, cols)].set(k, mode="drop")
+    cache_v = cache_v.at[lead + (rows, cols)].set(v, mode="drop")
     return cache_k, cache_v
 
 
@@ -119,33 +134,31 @@ def prefix_block_hashes(ids, block: int = PREFIX_BLOCK) -> List[int]:
     return out
 
 
-def copy_prefix(cache_k, cache_v, src_idx, dst_idx, length, width: int):
-    """Batched cross-slot prefix copy on a stacked [L, B, M, KV, hd] pool.
+def copy_prefix(pool, src_idx, dst_idx, length, width: int):
+    """Batched cross-slot prefix copy on one stacked [L, B, M, ...] pool.
 
     Row ``g`` copies cache lines [0, length[g]) of pool slot ``src_idx[g]``
     into slot ``dst_idx[g]`` across every layer at once — one gather plus
-    one drop-mode scatter per cache tensor (the batched dynamic-update
-    idiom of :func:`write_chunk`), regardless of how many borrowers seed
-    this step. ``width`` is the static gather width (>= max(length));
-    lines beyond ``length[g]`` are routed out of bounds and dropped.
+    one drop-mode scatter (the batched dynamic-update idiom of
+    :func:`write_chunk`), regardless of how many borrowers seed this step.
+    ``width`` is the static gather width (>= max(length)); lines beyond
+    ``length[g]`` are routed out of bounds and dropped.
     """
-    M = cache_k.shape[2]
+    M = pool.shape[2]
     G = src_idx.shape[0]
     assert width <= M, f"copy width {width} exceeds cache lines {M}"
-    src_k = cache_k[:, src_idx, :width]                      # [L,G,W,KV,hd]
-    src_v = cache_v[:, src_idx, :width]
+    src = pool[:, src_idx, :width]                           # [L,G,W,...]
     cols = jnp.broadcast_to(jnp.arange(width)[None, :], (G, width))
     cols = jnp.where(cols < length[:, None], cols, M)        # [G, W]
     rows = dst_idx[:, None]                                  # [G, 1]
-    cache_k = cache_k.at[:, rows, cols].set(src_k, mode="drop")
-    cache_v = cache_v.at[:, rows, cols].set(src_v, mode="drop")
-    return cache_k, cache_v
+    return pool.at[:, rows, cols].set(src, mode="drop")
 
 
 def write_decode(cache_k, cache_v, k, v, pos, window: Optional[int]):
     """Write one token per row at per-request absolute position ``pos`` [B].
 
-    cache [B, M, KV, hd]; k/v [B, 1, KV, hd]. A layer-stacked cache
+    cache [B, M, KV, hd]; k/v [B, 1, KV, hd] (trailing dims are free, so
+    latent lines [B, 1, C] go the same way). A layer-stacked cache
     [L, B, M, KV, hd] takes ``pos`` as ``(layer, pos)`` and is written at
     ``(layer, row, slot)`` alone: the decode layer scan carries the stack,
     so XLA scatters the B lines in place instead of slicing the layer out
@@ -154,7 +167,7 @@ def write_decode(cache_k, cache_v, k, v, pos, window: Optional[int]):
     layer = ()
     if isinstance(pos, tuple):
         layer, pos = (pos[0],), pos[1]
-    M = cache_k.shape[-3]
+    M = cache_k.shape[len(layer) + 1]
     slot = pos % M if window is not None else jnp.minimum(pos, M - 1)
     idx = layer + (jnp.arange(k.shape[0]), slot)
     cache_k = cache_k.at[idx].set(k[:, 0], unique_indices=True)

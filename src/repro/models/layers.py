@@ -13,6 +13,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 # --------------------------------------------------------------------------
@@ -76,6 +77,43 @@ def rope_cos_sin(positions, head_dim: int, theta: float):
     return jnp.cos(ang), jnp.sin(ang)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_inv_freq(cfg, dim: int) -> np.ndarray:
+    """Inverse rotary frequencies of ``dim`` dimensions (float32, static).
+
+    Plain rope: ``theta^(-2i/dim)``. Under YaRN (``cfg.yarn_factor > 1``)
+    each frequency ramps from that value (fast dimensions, kept) to it
+    divided by the factor (slow dimensions, interpolated) over the
+    correction range of ``beta_fast`` / ``beta_slow`` rotations within the
+    original ``yarn_original_max_pos`` positions (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``)."""
+    extra = 1.0 / (cfg.rope_theta ** (np.arange(0, dim, 2) / dim))
+    f = cfg.yarn_factor
+    if f <= 1:
+        return extra.astype(np.float32)
+
+    def corr(rot):
+        return (dim * math.log(cfg.yarn_original_max_pos / (rot * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    lo = max(math.floor(corr(cfg.yarn_beta_fast)), 0)
+    hi = min(math.ceil(corr(cfg.yarn_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    keep = 1.0 - ramp
+    return (extra / f * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def rope_cos_sin_freq(positions, inv_freq, scale: float = 1.0):
+    """positions [..., S] and static ``inv_freq`` [hd//2] -> cos/sin
+    [..., S, hd//2] (float32), each times ``scale``."""
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_freq)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
 def apply_rope(x, cos, sin):
     """x [..., S, H, hd]; cos/sin [..., S, hd//2] (broadcast over heads)."""
     half = x.shape[-1] // 2
@@ -101,7 +139,8 @@ def _expand_kv(k, n_rep: int):
 
 
 def attention(q, k, v, *, causal: bool, window: Optional[int] = None,
-              q_offset=0, kv_len: Optional[jnp.ndarray] = None):
+              q_offset=0, kv_len: Optional[jnp.ndarray] = None,
+              scale: Optional[float] = None):
     """Scaled dot-product attention with GQA, causal and sliding-window masks.
 
     q: [B, Sq, H, hd]; k/v: [B, Sk, KV, hd]. ``q_offset`` is the absolute
@@ -109,14 +148,15 @@ def attention(q, k, v, *, causal: bool, window: Optional[int] = None,
     or a per-row [B] array (ragged chunked prefill: each batch row sits at
     its own offset into its KV lines).
     ``kv_len`` optionally masks out cache positions >= kv_len (ragged decode).
-    Returns [B, Sq, H, hd].
+    ``scale`` defaults to ``hd ** -0.5``; v may have its own head size.
+    Returns [B, Sq, H, hd_v].
     """
     b, sq, h, hd = q.shape
     sk, kv = k.shape[1], k.shape[2]
     n_rep = h // kv
     k = _expand_kv(k, n_rep)
     v = _expand_kv(v, n_rep)
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     q_off = jnp.asarray(q_offset)
@@ -177,7 +217,8 @@ def decode_attention(q, k, v, *, kv_len):
 
 
 def ragged_prefill_attention(q, k, v, *, pos0, take=None,
-                             window: Optional[int] = None):
+                             window: Optional[int] = None,
+                             scale: Optional[float] = None):
     """Reference twin of ``kernels.ops.ragged_prefill_attention``.
 
     q [G,Sq,H,hd]; k/v [G,W,KV,hd]; pos0/take [G]. Row ``g`` carries
@@ -189,7 +230,8 @@ def ragged_prefill_attention(q, k, v, *, pos0, take=None,
     positions). ``take=None`` means every row is fully valid.
     """
     g, s = q.shape[:2]
-    out = attention(q, k, v, causal=True, window=window, q_offset=pos0)
+    out = attention(q, k, v, causal=True, window=window, q_offset=pos0,
+                    scale=scale)
     if take is None:
         return out
     valid = jnp.arange(s)[None, :] < take[:, None]

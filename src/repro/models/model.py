@@ -6,6 +6,9 @@ Every architecture exposes the same four entry points:
   serve_prefill(params, cfg, batch, cache)  -> (last logits, cache)
   serve_decode(params, cfg, token, pos, cache) -> (logits, cache)
 
+Latent-attention configs (``cfg.is_mla``, DeepSeek-V2) take the
+``transformer.latent_*`` stack and the latent cache under the same names.
+
 ``input_specs(cfg, shape)`` returns jax.ShapeDtypeStruct stand-ins for
 every model input of the given benchmark shape (no device allocation) —
 this is what launch/dryrun.py lowers against.
@@ -42,6 +45,9 @@ def init_params(cfg: ModelConfig, key, *, dtype=None):
 
 def forward(params, cfg: ModelConfig, batch: Dict[str, Any], *,
             remat: bool = False, remat_policy: Optional[str] = None):
+    if cfg.is_mla:
+        return T.latent_forward(params, cfg, batch, remat=remat,
+                                remat_policy=remat_policy)
     if cfg.family in ("dense", "moe", "vlm"):
         return T.decoder_forward(params, cfg, batch, remat=remat,
                                  remat_policy=remat_policy)
@@ -66,6 +72,8 @@ def loss_fn(params, cfg: ModelConfig, batch, **kw):
 # --------------------------------------------------------------------------
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, dtype=jnp.bfloat16):
+    if cfg.is_mla:
+        return KV.init_latent_cache(cfg, batch, max_len, dtype)
     if cfg.family in ("dense", "moe", "vlm"):
         return KV.init_attn_cache(cfg, batch, max_len, cfg.n_layers, dtype)
     if cfg.family == "audio":
@@ -85,6 +93,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, *, dtype=jnp.bfloat16
 
 
 def serve_prefill(params, cfg: ModelConfig, batch, cache):
+    if cfg.is_mla:
+        b, s = batch["tokens"].shape
+        return T.latent_prefill_chunk(
+            params, cfg, batch["tokens"], cache, jnp.arange(b, dtype=jnp.int32),
+            jnp.zeros((b,), jnp.int32), jnp.full((b,), s, jnp.int32))
     if cfg.family in ("dense", "moe", "vlm"):
         return T.decoder_prefill(params, cfg, batch, cache)
     if cfg.family == "audio":
@@ -96,14 +109,21 @@ def serve_prefill(params, cfg: ModelConfig, batch, cache):
     raise ValueError(cfg.family)
 
 
-# Families whose prompts can be prefilled as padded/ragged chunked batches
-# written straight into the slot-pooled cache. Attention-only decoders
-# qualify: causal masking keeps padded/garbage lines out of every valid
-# query. MoE is excluded (expert-capacity competition couples batch rows,
-# so batched outputs would not be token-identical to batch-1); recurrent
-# families (ssm/hybrid) and encoder-decoder/vlm prefixes carry state that
-# padding would corrupt — they use the engine's legacy per-slot path.
-CHUNKED_PREFILL_FAMILIES = ("dense",)
+def chunked_prefill_ok(cfg: ModelConfig) -> bool:
+    """Whether prompts can be prefilled as padded/ragged chunked batches
+    written straight into the slot-pooled cache. Causal masking keeps
+    padded lines out of every valid query, so attention-only decoders
+    qualify where no batch row's result depends on another's: dense
+    decoders, and latent-attention expert stacks with full attention,
+    whose expert layers route dropless by construction
+    (``transformer.latent_*`` always calls ``moe.moe_dropless``).
+    Capacity-routed experts (mixtral, kimi: tokens over capacity are
+    dropped, which couples rows), recurrent families (ssm/hybrid) and
+    encoder-decoder/vlm prefixes keep the engine's legacy per-slot path."""
+    if cfg.family == "dense":
+        return True
+    return (cfg.is_mla and cfg.family == "moe"
+            and cfg.decode_window() is None)
 
 
 def serve_prefill_chunk(params, cfg: ModelConfig, tokens, cache, slot_idx,
@@ -112,17 +132,34 @@ def serve_prefill_chunk(params, cfg: ModelConfig, tokens, cache, slot_idx,
 
     tokens [G, S] right-padded ids; slot_idx/pos0/take [G]; ``kv_width``
     statically bounds how many cache lines attention reads — see
-    ``transformer.decoder_prefill_chunk``. Only families in
-    ``CHUNKED_PREFILL_FAMILIES`` support this path.
+    ``transformer.decoder_prefill_chunk``. Only configs that
+    ``chunked_prefill_ok`` admits support this path.
     """
-    if cfg.family in CHUNKED_PREFILL_FAMILIES:
-        return T.decoder_prefill_chunk(params, cfg, tokens, cache, slot_idx,
-                                       pos0, take, kv_width=kv_width)
-    raise NotImplementedError(
-        f"chunked slot prefill is not supported for family {cfg.family!r}")
+    if not chunked_prefill_ok(cfg):
+        raise NotImplementedError(
+            f"chunked slot prefill is not supported for {cfg.arch_id!r}")
+    if cfg.is_mla:
+        return T.latent_prefill_chunk(params, cfg, tokens, cache, slot_idx,
+                                      pos0, take, kv_width=kv_width)
+    return T.decoder_prefill_chunk(params, cfg, tokens, cache, slot_idx,
+                                   pos0, take, kv_width=kv_width)
+
+
+def serve_decode_counted(params, cfg: ModelConfig, token, pos, cache,
+                         live):
+    """``serve_decode`` plus the expert load of the step's ``live`` [B]
+    rows: for a dropless expert stack, [held + 1] int32 (pairs per held
+    expert, then held experts hit, summed over layers —
+    ``transformer.latent_decode``); None for every other config, whose
+    program is ``serve_decode``'s."""
+    if cfg.is_mla:
+        return T.latent_decode(params, cfg, token, pos, cache, live)
+    return (*serve_decode(params, cfg, token, pos, cache), None)
 
 
 def serve_decode(params, cfg: ModelConfig, token, pos, cache):
+    if cfg.is_mla:
+        return T.latent_decode(params, cfg, token, pos, cache)[:2]
     if cfg.family in ("dense", "moe", "vlm"):
         return T.decoder_decode(params, cfg, token, pos, cache)
     if cfg.family == "audio":

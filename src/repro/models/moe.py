@@ -25,14 +25,16 @@ from repro.models import layers as L
 
 
 def init_moe(key, cfg, *, dtype=jnp.float32):
+    """Router over all ``n_experts``; expert banks of the experts held."""
     E, D, F = cfg.n_experts, cfg.d_model, cfg.d_ff
+    Eh = cfg.experts_held
     ks = jax.random.split(key, 5)
     s = 1.0 / math.sqrt(D)
     p = {
         "router": L.dense_init(ks[0], D, E, dtype=jnp.float32),
-        "w_gate": jax.random.normal(ks[1], (E, D, F), dtype) * s,
-        "w_up": jax.random.normal(ks[2], (E, D, F), dtype) * s,
-        "w_down": jax.random.normal(ks[3], (E, F, D), dtype) / math.sqrt(F),
+        "w_gate": jax.random.normal(ks[1], (Eh, D, F), dtype) * s,
+        "w_up": jax.random.normal(ks[2], (Eh, D, F), dtype) * s,
+        "w_down": jax.random.normal(ks[3], (Eh, F, D), dtype) / math.sqrt(F),
     }
     if cfg.n_shared_experts:
         p["shared"] = L.init_mlp(ks[4], cfg, dtype=dtype,
@@ -206,6 +208,54 @@ def moe_forward_ep(p, cfg, x, mesh) -> Tuple[jnp.ndarray, jnp.ndarray]:
         from repro.models import layers as L
         y = y + L.mlp(p["shared"], cfg, x)
     return y, aux
+
+
+def moe_dropless(p, cfg, x, rows=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Dropless expert layer over the experts this chip holds.
+
+    ``g = softmax(W_g x)`` in float32 over all ``n_experts``; greedy
+    top-k (renormalised only under ``norm_topk_prob``, times
+    ``routed_scaling``); ``y = S(x) + sum over picked-and-held i of
+    g_i E_i(x)``, where ``S`` is the shared expert. Every routed (token,
+    held expert) pair is computed: the pairs are sorted by expert and run
+    through one grouped matmul per projection (``jax.lax.ragged_dot``),
+    so the work follows the routed pairs, no capacity applies, and a
+    row's result does not depend on the other rows. Pairs routed to
+    experts held elsewhere sort last, past every group, and add nothing.
+
+    x [B,S,D] -> (y [B,S,D], picks [held] int32: the pairs each held
+    expert computed for the rows that ``rows`` [B] marks, every row where
+    it is None)."""
+    B, S, D = x.shape
+    K, n = cfg.top_k, cfg.experts_held
+    xt = x.reshape(-1, D)
+    logits = xt.astype(jnp.float32) @ p["router"]["w"].astype(jnp.float32)
+    gate, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), K)   # [T,K]
+    if cfg.norm_topk_prob:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    gate = gate * cfg.routed_scaling
+    local = idx - cfg.expert_offset
+    mine = (local >= 0) & (local < n)
+    e = jnp.where(mine, local, n).reshape(-1)                      # [T*K]
+    order = jnp.argsort(e, stable=True)
+    pair = e[:, None] == jnp.arange(n)[None, :]                     # [T*K,n]
+    sizes = jnp.sum(pair, axis=0, dtype=jnp.int32)                  # [n]
+    picks = sizes if rows is None else jnp.sum(
+        pair & jnp.repeat(rows, S * K)[:, None], axis=0, dtype=jnp.int32)
+    tok = order // K
+    xs = xt[tok]
+    h = jax.nn.silu(jax.lax.ragged_dot(xs, p["w_gate"], sizes)) * \
+        jax.lax.ragged_dot(xs, p["w_up"], sizes)
+    ys = jax.lax.ragged_dot(h, p["w_down"], sizes)                  # [T*K,D]
+    held = (e < n)[order]
+    w = gate.reshape(-1)[order]
+    contrib = jnp.where(held[:, None], ys.astype(jnp.float32) * w[:, None],
+                        0.0)
+    y = jnp.zeros((xt.shape[0], D), jnp.float32).at[tok].add(contrib)
+    y = y.astype(x.dtype)
+    if "shared" in p:
+        y = y + L.mlp(p["shared"], cfg, xt)
+    return y.reshape(B, S, D), picks
 
 
 def moe_forward_dense(p, cfg, x) -> Tuple[jnp.ndarray, jnp.ndarray]:

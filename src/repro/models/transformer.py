@@ -7,12 +7,14 @@ the same scan.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
+from repro.models import mla as MLA
 from repro.models import moe as M
 from repro.models import ssm as S
 from repro.models import kvcache as KV
@@ -52,17 +54,21 @@ def _maybe_remat(fn, enabled: bool, policy: Optional[str] = None):
 # Dense / MoE / VLM decoder layer
 # ==========================================================================
 
-def init_decoder_layer(key, cfg, *, dtype=jnp.float32):
+def init_decoder_layer(key, cfg, *, dtype=jnp.float32, dense=False):
+    """One layer; ``dense`` makes a leading dense layer of an expert stack
+    (a SwiGLU of width ``dense_d_ff``)."""
     ks = jax.random.split(key, 4)
+    attn = MLA.init_mla if cfg.is_mla else L.init_attn
     p = {
         "ln1": L.norm_init(cfg.d_model, dtype=dtype),
-        "attn": L.init_attn(ks[0], cfg, dtype=dtype),
+        "attn": attn(ks[0], cfg, dtype=dtype),
         "ln2": L.norm_init(cfg.d_model, dtype=dtype),
     }
-    if cfg.family == "moe":
+    if cfg.family == "moe" and not dense:
         p["moe"] = M.init_moe(ks[1], cfg, dtype=dtype)
     else:
-        p["mlp"] = L.init_mlp(ks[1], cfg, dtype=dtype)
+        p["mlp"] = L.init_mlp(ks[1], cfg, dtype=dtype,
+                              d_ff=cfg.dense_d_ff if dense else None)
     return p
 
 
@@ -135,12 +141,18 @@ def decoder_layer_decode(p, cfg, h, ck, cv, layer, pos, *, window=None):
 def init_decoder_model(key, cfg, *, dtype=jnp.float32):
     ks = jax.random.split(key, 4)
     Vp = cfg.padded_vocab()
+    k_dense = cfg.first_k_dense
     p = {
         "embed": L.init_embedding(ks[0], Vp, cfg.d_model, dtype=dtype),
         "layers": stacked_init(
-            lambda k: init_decoder_layer(k, cfg, dtype=dtype), ks[1], cfg.n_layers),
+            lambda k: init_decoder_layer(k, cfg, dtype=dtype), ks[1],
+            cfg.n_layers - k_dense),
         "final_norm": L.norm_init(cfg.d_model, dtype=dtype),
     }
+    if k_dense:
+        p["dense_layers"] = stacked_init(
+            lambda k: init_decoder_layer(k, cfg, dtype=dtype, dense=True),
+            ks[3], k_dense)
     if not cfg.tie_embeddings:
         p["lm_head"] = L.init_embedding(ks[2], Vp, cfg.d_model, dtype=dtype)
     return p
@@ -239,9 +251,9 @@ def decoder_layer_prefill_chunk(p_l, cfg, h, ck, cv, slot_idx, positions,
     g_, s_ = h.shape[:2]
     h = h + L.dense(p_l["attn"]["wo"], out.reshape(g_, s_, cfg.q_dim))
     hn = L.rms_norm(p_l["ln2"], h, cfg.norm_eps)
-    # dense layers only (CHUNKED_PREFILL_FAMILIES): moe is excluded
-    # because expert-capacity competition couples batch rows, which
-    # would break the token-identity guarantee of this path
+    # dense layers only (model.chunked_prefill_ok): capacity-routed moe
+    # is excluded because expert-capacity competition couples batch rows,
+    # which would break the token-identity guarantee of this path
     y = L.mlp(p_l["mlp"], cfg, hn)
     return h + y, ck, cv
 
@@ -297,6 +309,179 @@ def decoder_decode(p, cfg, token, pos, cache):
                                 (p["layers"], jnp.arange(cfg.n_layers)))
     h = L.rms_norm(p["final_norm"], h, cfg.norm_eps)
     return _logits(p, cfg, h), {"k": ck, "v": cv}
+
+
+# ==========================================================================
+# Latent-attention decoder (MLA, DeepSeek-V2): ``first_k_dense`` dense
+# layers (``params["dense_layers"]``), then dropless expert layers
+# (``params["layers"]``), each stack one layer scan. The cache is the
+# latent pool of ``kvcache.init_latent_cache``. The attention part of
+# every layer runs under ``jax.named_scope("mla")`` and the expert part
+# (router, held experts, shared expert) under ``jax.named_scope("moe")``,
+# so their device ops carry the scope in a profile.
+# ==========================================================================
+
+_BANKS = ("w_gate", "w_up", "w_down")
+
+
+def _scan_latent(p, cfg, body, carry, wrap=lambda f: f):
+    """Run ``body(carry, p_l, layer, ffn) -> carry`` over every layer in
+    order, one layer scan per stack (the dense layers, then the expert
+    layers). ``layer`` is the layer's index in the whole model (the
+    cache's); ``ffn(h, rows=None)`` is its residual FFN step
+    (:func:`_latent_ffn`). The held-expert banks stay out of the scan's
+    slices: the expert layer indexes the stacked banks by layer inside
+    the ``moe`` scope, so the device work of taking a layer's banks is
+    counted as expert work in a profile. ``wrap`` wraps the scan step
+    (remat)."""
+    stacks = [(p["layers"], cfg.first_k_dense)]
+    if cfg.first_k_dense:
+        stacks.insert(0, (p["dense_layers"], 0))
+    for stack, first in stacks:
+        banks = None
+        if "moe" in stack:
+            moe = stack["moe"]
+            banks = {k: moe[k] for k in _BANKS}
+            stack = dict(stack, moe={k: v for k, v in moe.items()
+                                     if k not in _BANKS})
+        n = jax.tree.leaves(stack)[0].shape[0]
+
+        def step(c, xs, banks=banks, first=first):
+            p_l, i = xs
+            return body(c, p_l, first + i,
+                        partial(_latent_ffn, p_l, cfg, banks, i)), None
+
+        carry, _ = layer_scan(wrap(step), carry, (stack, jnp.arange(n)))
+    return carry
+
+
+def _latent_ffn(p_l, cfg, banks, i, h, rows=None):
+    """Residual FFN step: (h + ffn(norm(h)), picks per held expert of the
+    rows ``rows`` marks, or None for a dense layer). ``banks`` are the
+    stack's expert banks [layers, held, ...], ``i`` the layer's index in
+    the stack."""
+    hn = L.rms_norm(p_l["ln2"], h, cfg.norm_eps)
+    if banks is None:
+        return h + L.mlp(p_l["mlp"], cfg, hn), None
+    with jax.named_scope("moe"):
+        pm = dict(p_l["moe"], **{
+            k: jax.lax.dynamic_index_in_dim(b, i, 0, keepdims=False)
+            for k, b in banks.items()})
+        y, picks = M.moe_dropless(pm, cfg, hn, rows)
+    return h + y, picks
+
+
+def latent_forward(p, cfg, batch, *, remat: bool = False,
+                   remat_policy: Optional[str] = None):
+    """Training/scoring forward. Returns (logits, aux_loss = 0)."""
+    h = L.embed(p["embed"], batch["tokens"])
+    b, s = h.shape[:2]
+    positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
+    pos0 = jnp.zeros((b,), jnp.int32)
+
+    def body(h, p_l, layer, ffn):
+        a = p_l["attn"]
+        with jax.named_scope("mla"):
+            qn, qp, ckv, kpe = MLA.project(
+                a, cfg, L.rms_norm(p_l["ln1"], h, cfg.norm_eps), positions)
+            h = h + L.dense(a["wo"], MLA.attend_lines(a, cfg, qn, qp, ckv,
+                                                      kpe, pos0))
+        return ffn(h)[0]
+
+    h = _scan_latent(p, cfg, body, h,
+                     lambda f: _maybe_remat(f, remat, remat_policy))
+    h = L.rms_norm(p["final_norm"], h, cfg.norm_eps)
+    return _logits(p, cfg, h), jnp.zeros((), jnp.float32)
+
+
+def latent_prefill_chunk(p, cfg, tokens, cache, slot_idx, pos0, take,
+                         kv_width=None):
+    """Batched ragged chunked prefill into the latent pool, as
+    :func:`decoder_prefill_chunk` for dense decoders. The layer scans
+    carry the layer-stacked pool: each layer scatters the chunk's latent
+    lines at (layer, row, line) in place, then attends the first
+    ``kv_width`` lines of each row's slot, decompressed to per-head K
+    and V. Returns (logits [G, 1, V] at each row's last valid token,
+    cache)."""
+    h = L.embed(p["embed"], tokens)
+    S = tokens.shape[1]
+    positions = pos0[:, None] + jnp.arange(S)[None, :]
+    w = kv_width if kv_width is not None else cache["ckv"].shape[2]
+
+    def body(carry, p_l, layer, ffn):
+        h, ckv, kpe = carry
+        a = p_l["attn"]
+        with jax.named_scope("mla"):
+            qn, qp, c, r = MLA.project(
+                a, cfg, L.rms_norm(p_l["ln1"], h, cfg.norm_eps), positions)
+            ckv, kpe = KV.write_chunk(ckv, kpe, c.astype(ckv.dtype),
+                                      r.astype(kpe.dtype), slot_idx, pos0,
+                                      take, layer=layer)
+
+            def lines(pool):
+                own = jax.lax.dynamic_index_in_dim(pool, layer, 0,
+                                                   keepdims=False)
+                return jnp.take(own[:, :w], slot_idx, axis=0).astype(qn.dtype)
+
+            out = MLA.attend_lines(a, cfg, qn, qp, lines(ckv), lines(kpe),
+                                   pos0, take)
+            h = h + L.dense(a["wo"], out)
+        return ffn(h)[0], ckv, kpe
+
+    h, ckv, kpe = _scan_latent(p, cfg, body, (h, cache["ckv"], cache["kpe"]))
+    h = L.rms_norm(p["final_norm"], h, cfg.norm_eps)
+    last = jnp.take_along_axis(h, (take - 1)[:, None, None], axis=1)
+    return _logits(p, cfg, last), {"ckv": ckv, "kpe": kpe}
+
+
+def latent_decode(p, cfg, token, pos, cache, live=None):
+    """token [B,1]; pos [B]; live [B] (nonzero for rows that serve a
+    request; every row where None). Returns (logits [B,1,V], cache,
+    load).
+
+    Each layer writes its B latent lines into the carried pool in place
+    and attends in the absorbed form (``mla.attend_absorbed``): it reads
+    the stored latent lines and never expands per-head K/V. ``load`` is,
+    for an expert stack, [held + 1] int32 summed over the expert layers
+    and counted over the live rows only: the (token, expert) pairs each
+    held expert computed, then the count of held experts that computed
+    any; None for a stack without experts.
+    """
+    h = L.embed(p["embed"], token)
+    positions = pos[:, None]
+    kvl = KV.valid_len(pos, cache["ckv"].shape[2], None)
+    n = cfg.experts_held
+    rows = None if live is None else live > 0
+
+    def body(carry, p_l, layer, ffn):
+        h, ckv, kpe, load = carry
+        a = p_l["attn"]
+        with jax.named_scope("mla"):
+            qn, qp, c, r = MLA.project(
+                a, cfg, L.rms_norm(p_l["ln1"], h, cfg.norm_eps), positions)
+            ckv, kpe = KV.write_decode(ckv, kpe, c.astype(ckv.dtype),
+                                       r.astype(kpe.dtype), (layer, pos),
+                                       None)
+
+            def lines(pool):
+                return jax.lax.dynamic_index_in_dim(
+                    pool, layer, 0, keepdims=False).astype(qn.dtype)
+
+            out = MLA.attend_absorbed(a, cfg, qn, qp, lines(ckv), lines(kpe),
+                                      kvl)
+            h = h + L.dense(a["wo"], out)
+        h, picks = ffn(h, rows)
+        if picks is not None:
+            load = load + jnp.concatenate(
+                [picks, jnp.sum(picks > 0, dtype=jnp.int32)[None]])
+        return h, ckv, kpe, load
+
+    h, ckv, kpe, load = _scan_latent(
+        p, cfg, body, (h, cache["ckv"], cache["kpe"],
+                       jnp.zeros((n + 1,), jnp.int32)))
+    h = L.rms_norm(p["final_norm"], h, cfg.norm_eps)
+    return (_logits(p, cfg, h), {"ckv": ckv, "kpe": kpe},
+            load if cfg.family == "moe" else None)
 
 
 # ==========================================================================

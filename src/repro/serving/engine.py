@@ -34,10 +34,13 @@ Hot path (dense decoders — the HybridFlow edge/cloud executor archs):
   advanced inside the jitted step; inactive slots are parked at
   ``max_len - 1`` (a line no live request ever attends).
 
-Non-batchable families (moe: expert-capacity couples batch rows; vlm /
-audio / hybrid / ssm: prefix or recurrent state) fall back to the legacy
-per-slot batch-1 prefill, which is kept as the reference path
-(``batched_prefill=False`` forces it for any family).
+Configs that ``model.chunked_prefill_ok`` refuses (capacity-routed moe:
+expert capacity couples batch rows; vlm / audio / hybrid / ssm: prefix or
+recurrent state) fall back to the legacy per-slot batch-1 prefill, which
+is kept as the reference path (``batched_prefill=False`` forces it for
+any config). Dropless expert stacks (deepseek-v2-lite) take the batched
+path, and their decode step also returns its expert load, accumulated in
+``stats`` (``expert_picks_<i>``, ``experts_hit``).
 
 Engine steps are split into a *launch* phase (host builds inputs and
 issues the jitted call — JAX dispatch is async) and a *commit* phase
@@ -120,6 +123,7 @@ class _DecodePass:
 
     live_slots: List[int]
     nxt: object           # device array of sampled ids [slots]
+    load: object = None   # device array of expert load [held + 1], or None
 
 
 def _device_sample(logits, key, temps):
@@ -221,9 +225,9 @@ def _jit_copy(width: int):
         fn = _COPY_JITS.get(width)
         if fn is None:
             def copy_fn(cache, src_idx, dst_idx, length):
-                k, v = KV.copy_prefix(cache["k"], cache["v"], src_idx,
-                                      dst_idx, length, width)
-                return dict(cache, k=k, v=v)
+                # every pool array: K and V, or the latent c_kv and k_pe
+                return {n: KV.copy_prefix(x, src_idx, dst_idx, length, width)
+                        for n, x in cache.items()}
             fn = jax.jit(copy_fn, donate_argnums=(0,))
             _COPY_JITS[width] = fn
         return fn
@@ -244,10 +248,14 @@ def _jit_steps(cfg: ModelConfig, max_len: int, use_pallas: bool = False):
         # lands on a line no live request ever attends (requests finish at
         # pos >= max_len-1 before reading it)
         pos_eff = jnp.where(live > 0, pos, max_len - 1)
-        logits, cache = M.serve_decode(params, cfg, tokens, pos_eff, cache)
+        logits, cache, load = M.serve_decode_counted(params, cfg, tokens,
+                                                     pos_eff, cache, live)
         key, sub = jax.random.split(key)
         nxt = _device_sample(logits[:, 0], sub, temps)
-        return nxt, pos + live, cache, key
+        if load is None:
+            return nxt, pos + live, cache, key
+        # expert stacks: the step's expert load rides out with the ids
+        return nxt, pos + live, cache, key, load
 
     def prefill_fn(params, tokens, slot_idx, pos0, take, pos, cache, key,
                    temps, kv_width):
@@ -323,8 +331,7 @@ class ServingEngine:
         self._pos_np = np.full(batch_slots, max_len - 1, np.int32)
         self.prefill_chunk = (None if prefill_chunk is None
                               else max(1, min(prefill_chunk, max_len)))
-        self.batched_prefill = (batched_prefill and
-                                cfg.family in M.CHUNKED_PREFILL_FAMILIES)
+        self.batched_prefill = batched_prefill and M.chunked_prefill_ok(cfg)
         self.active: List[Optional[Request]] = [None] * batch_slots
         self.queue: List[Request] = []
         self._rid = 0
@@ -351,6 +358,13 @@ class ServingEngine:
                       # padding included: beside tokens_out and
                       # prefill_tokens they give the padded share
                       "decode_rows": 0, "prefill_positions": 0}
+        if cfg.family == "moe" and M.chunked_prefill_ok(cfg):
+            # decode's expert load, summed over steps and layers: the
+            # (token, expert) pairs each held expert computed, and the
+            # held experts that computed any
+            self.stats.update({f"expert_picks_{i}": 0
+                               for i in range(cfg.experts_held)},
+                              experts_hit=0)
 
     def _steps(self):
         """Resolve the jitted step pair against the CURRENT kernel-dispatch
@@ -708,19 +722,23 @@ class ServingEngine:
                 temps[i] = self.active[i].temperature
                 live[i] = 1
             decode_step, _ = self._steps()
-            nxt, self.pos, self.cache, self.key = decode_step(
+            nxt, self.pos, self.cache, self.key, *load = decode_step(
                 self.params, jnp.asarray(tokens), self.pos, self.cache,
                 self.key, jnp.asarray(temps), jnp.asarray(live))
             self.stats["decode_rows"] += self.slots
             self._track_retraces()
-            return _DecodePass(live_slots, nxt)
+            return _DecodePass(live_slots, nxt, load[0] if load else None)
 
     # reprolint: hot
     def _decode_commit(self, d: _DecodePass) -> List[Request]:
         """Sync the launched decode step and retire finished requests."""
         with span("engine.sync"):
-            # reprolint: disable=host-sync-in-hot-path -- the ONE host transfer per decode step (sampled ids)
-            nxt_np = np.asarray(d.nxt)
+            # reprolint: disable=host-sync-in-hot-path -- the ONE host transfer per decode step (sampled ids, and the expert load where there is one)
+            nxt_np, load_np = jax.device_get((d.nxt, d.load))
+        if load_np is not None:
+            for i in range(len(load_np) - 1):
+                self.stats[f"expert_picks_{i}"] += int(load_np[i])
+            self.stats["experts_hit"] += int(load_np[-1])
         finished: List[Request] = []
         for i in d.live_slots:
             req = self.active[i]

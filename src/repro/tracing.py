@@ -21,6 +21,22 @@ Spans, by layer:
 * engine (``serving/engine.py``): ``engine.step``, ``engine.admit``,
   ``engine.prefill_launch``, ``engine.decode_launch``, ``engine.sync``
   (the blocking device-to-host read of sampled ids).
+
+Device scopes: the latent-attention stack (``models/transformer.py``
+``latent_*``, deepseek-v2-lite) traces each layer's attention under
+``jax.named_scope("mla")`` and its expert layer (router, held experts,
+shared expert) under ``jax.named_scope("moe")``, so the step programs'
+device ops carry the scope in their compiled ``op_name`` metadata.
+
+Counters: ``ServingEngine.stats`` (beside token, step and prefix
+counts) holds, for a dropless expert stack, ``expert_picks_<i>`` (the
+(token, expert) pairs held expert ``i`` computed in decode) and
+``experts_hit`` (held experts that computed any, per layer and step),
+summed over decode steps and layers, over the rows that serve a request
+(parked slots and slots still prefilling are computed but not counted).
+They cover the engine's whole life, warm-up and drain included. The
+decode program returns them with the sampled ids; the engine reads both
+in its one sync.
 """
 from __future__ import annotations
 

@@ -61,6 +61,7 @@ def test_param_count_sanity():
         "qwen2-1.5b": (1.2e9, 2.2e9),
         "internlm2-1.8b": (1.4e9, 2.4e9),
         "kimi-k2-1t-a32b": (0.8e12, 1.3e12),
+        "deepseek-v2-lite": (15.5e9, 15.9e9),   # published: 15.7B
     }
     for arch, (lo, hi) in cases.items():
         n = get_config(arch).param_count()

@@ -88,9 +88,9 @@ def test_copy_prefix_matches_numpy_reference():
     for g in range(2):
         want_k[:, dst[g], :ln[g]] = k[:, src[g], :ln[g]]
         want_v[:, dst[g], :ln[g]] = v[:, src[g], :ln[g]]
-    got_k, got_v = KV.copy_prefix(jnp.asarray(k), jnp.asarray(v),
-                                  jnp.asarray(src), jnp.asarray(dst),
-                                  jnp.asarray(ln), width=16)
+    got_k, got_v = (KV.copy_prefix(jnp.asarray(x), jnp.asarray(src),
+                                   jnp.asarray(dst), jnp.asarray(ln),
+                                   width=16) for x in (k, v))
     np.testing.assert_array_equal(np.asarray(got_k), want_k)
     np.testing.assert_array_equal(np.asarray(got_v), want_v)
 
