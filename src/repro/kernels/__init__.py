@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the serving/training hot paths.
 
-Five kernels, each with a pure-jnp oracle (``ref.py``) it is allclose-
-validated against in interpret mode on CPU:
+Six kernels, each with a pure-jnp oracle it is allclose-validated
+against in interpret mode on CPU (``ref.py``; ``mla.attend_absorbed``
+for the latent one):
 
 * ``flash_attention``          — online-softmax prefill/training attention
   (causal, sliding window, GQA, scalar ``q_offset``), grid (B,H,Sq/BQ,Sk/BK).
@@ -12,6 +13,10 @@ validated against in interpret mode on CPU:
 * ``batched_decode_attention`` — one launch per decode tick: every slot's
   whole GQA head stack in a (B, M/BK) grid with per-slot ``kv_len`` in
   SMEM; this is what the engine dispatches.
+* ``latent_decode_attention``  — absorbed latent (MLA) decode: one grid
+  step per live block of each row, read in place from the layer-stacked
+  latent pool, and the row's new k_pe line written; what a TPU backend
+  always serves (``mla.decode_attend``), whatever ``use_pallas()`` says.
 * ``decode_attention``         — the original per-head flash-decode kernel,
   kept as the simple reference shape for roofline comparisons.
 * ``chunked_gla``              — chunked gated-linear-attention scan for the
@@ -50,7 +55,9 @@ Dispatch contract: model code never imports kernels directly — it calls
 ``layers._dispatch_attention`` / ``layers.ragged_prefill_attention``,
 which route to the jit'd wrappers in ``ops.py`` when
 ``dispatch.use_pallas()`` is on (REPRO_USE_PALLAS=1 or
-``pallas_enabled(True)``) and to the jnp reference otherwise. The
+``pallas_enabled(True)``) and to the jnp reference otherwise. The latent
+decode goes by backend alone: ``mla.decode_attend`` calls
+``ops.latent_decode_attention`` unless ``dispatch.interpret_mode()``. The
 wrappers own layout transposes ([B,S,H,hd] model layout -> [B,H,S,hd]
 blocked layout), GQA head mapping and padding to block multiples. Every
 kernel entry point takes ``interpret=None`` by default, which follows

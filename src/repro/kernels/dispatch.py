@@ -4,6 +4,8 @@ On TPU, enable with ``set_use_pallas(True)`` (or REPRO_USE_PALLAS=1). On
 CPU the kernels run in interpret mode and are only used by the kernel
 tests/benchmarks; models default to the XLA path. Interpret mode follows
 the backend and nothing else: a TPU process always compiles the kernels.
+The latent (MLA) decode kernel ignores the switch: a TPU backend always
+serves it (``models/mla.py`` ``decode_attend``).
 """
 from __future__ import annotations
 

@@ -16,6 +16,9 @@ import jax.numpy as jnp
 
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.batched_decode_attention import batched_decode_attention_bhmd
+# the latent decode kernel takes the model's layout as it is: no wrapper
+from repro.kernels.latent_decode_attention import (  # noqa: F401
+    block_lines as latent_block_lines, latent_decode_attention)
 from repro.kernels.ragged_prefill_attention import ragged_prefill_attention_bhsd
 from repro.kernels.rmsnorm import rmsnorm_2d
 

@@ -61,12 +61,30 @@ def init_attn_cache(cfg, batch: int, max_len: int, n_layers: int,
 def init_latent_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16):
     """Latent-attention (MLA) cache: per layer and token one ``c_kv`` line
     [kv_lora_rank] and one roped ``k_pe`` line [qk_rope_head_dim], shared
-    by every head, in place of per-head K and V."""
+    by every head, in place of per-head K and V.
+
+    ``ckv`` is [L, B, M, C]; ``kpe`` is stored lines-minor, [L, B, R, M]
+    (``LINES_MINOR``): R = 64 values would fill half of each 128-lane tile
+    row, so the lines run along the lanes instead (the layout a TPU gives
+    the lines-major form of this pool anyway), and the decode kernel
+    reads [R, block] tiles of it in place."""
     L = cfg.n_layers
     return {
         "ckv": jnp.zeros((L, batch, max_len, cfg.kv_lora_rank), dtype),
-        "kpe": jnp.zeros((L, batch, max_len, cfg.qk_rope_head_dim), dtype),
+        "kpe": jnp.zeros((L, batch, cfg.qk_rope_head_dim, max_len), dtype),
     }
+
+
+# pool arrays whose line axis is last ([L, B, width, M]), not third
+LINES_MINOR = frozenset({"kpe"})
+
+
+def lines_major(pool):
+    """[..., width, M] <-> [..., M, width]: the lines-major view of a
+    lines-minor pool, and back. XLA writes (``write_chunk``,
+    ``write_decode``, ``copy_prefix``) go through this view, which leaves
+    the compiler free to lay the pool out as suits the write."""
+    return jnp.swapaxes(pool, -1, -2)
 
 
 def write_prefill(cache_k, cache_v, k, v, window: Optional[int]):
@@ -134,8 +152,10 @@ def prefix_block_hashes(ids, block: int = PREFIX_BLOCK) -> List[int]:
     return out
 
 
-def copy_prefix(pool, src_idx, dst_idx, length, width: int):
-    """Batched cross-slot prefix copy on one stacked [L, B, M, ...] pool.
+def copy_prefix(pool, src_idx, dst_idx, length, width: int,
+                lines_minor: bool = False):
+    """Batched cross-slot prefix copy on one stacked [L, B, M, ...] pool
+    (``lines_minor``: [L, B, width, M]).
 
     Row ``g`` copies cache lines [0, length[g]) of pool slot ``src_idx[g]``
     into slot ``dst_idx[g]`` across every layer at once — one gather plus
@@ -144,6 +164,9 @@ def copy_prefix(pool, src_idx, dst_idx, length, width: int):
     ``width`` is the static gather width (>= max(length)); lines beyond
     ``length[g]`` are routed out of bounds and dropped.
     """
+    if lines_minor:
+        return lines_major(copy_prefix(lines_major(pool), src_idx, dst_idx,
+                                       length, width))
     M = pool.shape[2]
     G = src_idx.shape[0]
     assert width <= M, f"copy width {width} exceeds cache lines {M}"

@@ -18,7 +18,11 @@ Prefill decompresses the lines it attends (``attend_lines``). Decode
 (``attend_absorbed``) never does: ``W_uk`` (the k_nope part of
 ``W_kvb``) is folded into the query, ``q_lat = q_nope W_uk^T`` [H, C],
 so ``q_nope . k_nope = q_lat . c_kv``; and ``W_uv`` into the output,
-``P v = (P c_kv) W_uv``. It reads only the stored latent lines.
+``P v = (P c_kv) W_uv``. It reads only the stored latent lines. On a
+TPU backend the served decode (``decode_attend``) reads them with
+one Pallas kernel straight out of the layer-stacked pool, live blocks
+only (``kernels/latent_decode_attention.py``); ``attend_absorbed`` is
+that kernel's reference twin and serves every other backend.
 """
 from __future__ import annotations
 
@@ -27,6 +31,9 @@ import math
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import dispatch as kd
+from repro.kernels import ops as kops
+from repro.models import kvcache as KV
 from repro.models import layers as L
 
 
@@ -97,14 +104,21 @@ def attend_lines(p, cfg, q_nope, q_pe, c_kv, k_pe, pos0, take=None):
     return out.reshape(g, q.shape[1], -1)
 
 
+def _absorb_query(p, cfg, q_nope, dtype):
+    """q_nope [B,1,H,N] -> (q_lat [B,H,C], W_kvb [C,H,N+V]) in ``dtype``:
+    W_uk folded into the query."""
+    w = _w_kvb(p, cfg).astype(dtype)
+    return (jnp.einsum("bhn,chn->bhc", q_nope[:, 0],
+                       w[..., :cfg.qk_nope_head_dim]), w)
+
+
 def attend_absorbed(p, cfg, q_nope, q_pe, c_kv, k_pe, kv_len):
     """One query per row (q_nope [B,1,H,N], q_pe [B,1,H,R]) against the
     row's stored lines (c_kv [B,M,C], k_pe [B,M,R]), the first ``kv_len``
     [B] of them valid, with W_uk and W_uv absorbed. Returns [B, 1, H*V]."""
     b = q_nope.shape[0]
     N = cfg.qk_nope_head_dim
-    w = _w_kvb(p, cfg).astype(c_kv.dtype)
-    q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w[..., :N])
+    q_lat, w = _absorb_query(p, cfg, q_nope, c_kv.dtype)
     s = (jnp.einsum("bhc,bmc->bhm", q_lat, c_kv,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bhr,bmr->bhm", q_pe[:, 0].astype(k_pe.dtype), k_pe,
@@ -116,3 +130,47 @@ def attend_absorbed(p, cfg, q_nope, q_pe, c_kv, k_pe, kv_len):
     o_lat = jnp.einsum("bhm,bmc->bhc", probs.astype(c_kv.dtype), c_kv)
     out = jnp.einsum("bhc,chv->bhv", o_lat, w[..., N:])
     return out.reshape(b, 1, -1)
+
+
+def attend_absorbed_kernel(p, cfg, q_nope, q_pe, k_new, ckv, kpe, layer,
+                           kv_len, **kernel_kw):
+    """``attend_absorbed`` over ``layer`` of the layer-stacked pools
+    (ckv [L,B,M,C], kpe [L,B,R,M]) by the Pallas kernel, which first
+    writes each row's new k_pe line (k_new [B,1,R]) at ``kv_len - 1``,
+    then reads the row's first ``kv_len`` lines in place: no layer slice
+    is made. ``kernel_kw`` (``bk``, ``interpret``) goes to the kernel.
+    Returns (out [B,1,H*V], kpe)."""
+    b = q_nope.shape[0]
+    q_lat, w = _absorb_query(p, cfg, q_nope, ckv.dtype)
+    o_lat, kpe = kops.latent_decode_attention(
+        q_lat, q_pe[:, 0], k_new[:, 0], ckv, kpe, layer, kv_len,
+        scale=softmax_scale(cfg), **kernel_kw)
+    out = jnp.einsum("bhc,chv->bhv", o_lat, w[..., cfg.qk_nope_head_dim:])
+    return out.reshape(b, 1, -1), kpe
+
+
+def decode_attend(p, cfg, q_nope, q_pe, c_new, k_new, ckv, kpe, layer, pos,
+                  kv_len):
+    """One decode step of the latent attention over ``layer`` of the
+    stacked pools: each row's new line (c_new [B,1,C], k_new [B,1,R]) is
+    written at ``pos`` in place, then the row's first ``kv_len`` lines are
+    attended in the absorbed form. On a TPU backend the Pallas kernel
+    writes the k_pe line and reads the live blocks straight out of the
+    pools; elsewhere the layer's lines are sliced out (cast to the query
+    dtype) through ``attend_absorbed``. Returns (out [B,1,H*V], ckv, kpe).
+    """
+    c_new, k_new = c_new.astype(ckv.dtype), k_new.astype(kpe.dtype)
+    if not kd.interpret_mode():
+        # the kernel writes the k_pe line; c_kv's is written here
+        line = jnp.minimum(pos, ckv.shape[2] - 1)
+        ckv = ckv.at[layer, jnp.arange(line.shape[0]), line].set(
+            c_new[:, 0], unique_indices=True)
+        out, kpe = attend_absorbed_kernel(p, cfg, q_nope, q_pe, k_new, ckv,
+                                          kpe, layer, kv_len)
+        return out, ckv, kpe
+    ckv, kpe = KV.write_decode(ckv, KV.lines_major(kpe), c_new, k_new,
+                               (layer, pos), None)
+    c_kv, k_pe = (jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
+                  .astype(q_nope.dtype) for x in (ckv, kpe))
+    out = attend_absorbed(p, cfg, q_nope, q_pe, c_kv, k_pe, kv_len)
+    return out, ckv, KV.lines_major(kpe)

@@ -414,7 +414,9 @@ def latent_prefill_chunk(p, cfg, tokens, cache, slot_idx, pos0, take,
         with jax.named_scope("mla"):
             qn, qp, c, r = MLA.project(
                 a, cfg, L.rms_norm(p_l["ln1"], h, cfg.norm_eps), positions)
-            ckv, kpe = KV.write_chunk(ckv, kpe, c.astype(ckv.dtype),
+            # k_pe is stored lines-minor: write and read its lines-major view
+            ckv, kpe = KV.write_chunk(ckv, KV.lines_major(kpe),
+                                      c.astype(ckv.dtype),
                                       r.astype(kpe.dtype), slot_idx, pos0,
                                       take, layer=layer)
 
@@ -426,7 +428,7 @@ def latent_prefill_chunk(p, cfg, tokens, cache, slot_idx, pos0, take,
             out = MLA.attend_lines(a, cfg, qn, qp, lines(ckv), lines(kpe),
                                    pos0, take)
             h = h + L.dense(a["wo"], out)
-        return ffn(h)[0], ckv, kpe
+        return ffn(h)[0], ckv, KV.lines_major(kpe)
 
     h, ckv, kpe = _scan_latent(p, cfg, body, (h, cache["ckv"], cache["kpe"]))
     h = L.rms_norm(p["final_norm"], h, cfg.norm_eps)
@@ -440,8 +442,10 @@ def latent_decode(p, cfg, token, pos, cache, live=None):
     load).
 
     Each layer writes its B latent lines into the carried pool in place
-    and attends in the absorbed form (``mla.attend_absorbed``): it reads
-    the stored latent lines and never expands per-head K/V. ``load`` is,
+    and attends in the absorbed form (``mla.decode_attend``): it
+    reads the stored latent lines and never expands per-head K/V; on a
+    TPU one kernel reads each row's live lines straight out of the
+    stacked pool. Rows that serve no request attend nothing. ``load`` is,
     for an expert stack, [held + 1] int32 summed over the expert layers
     and counted over the live rows only: the (token, expert) pairs each
     held expert computed, then the count of held experts that computed
@@ -452,6 +456,8 @@ def latent_decode(p, cfg, token, pos, cache, live=None):
     kvl = KV.valid_len(pos, cache["ckv"].shape[2], None)
     n = cfg.experts_held
     rows = None if live is None else live > 0
+    if rows is not None:
+        kvl = jnp.where(rows, kvl, 0)
 
     def body(carry, p_l, layer, ffn):
         h, ckv, kpe, load = carry
@@ -459,16 +465,8 @@ def latent_decode(p, cfg, token, pos, cache, live=None):
         with jax.named_scope("mla"):
             qn, qp, c, r = MLA.project(
                 a, cfg, L.rms_norm(p_l["ln1"], h, cfg.norm_eps), positions)
-            ckv, kpe = KV.write_decode(ckv, kpe, c.astype(ckv.dtype),
-                                       r.astype(kpe.dtype), (layer, pos),
-                                       None)
-
-            def lines(pool):
-                return jax.lax.dynamic_index_in_dim(
-                    pool, layer, 0, keepdims=False).astype(qn.dtype)
-
-            out = MLA.attend_absorbed(a, cfg, qn, qp, lines(ckv), lines(kpe),
-                                      kvl)
+            out, ckv, kpe = MLA.decode_attend(a, cfg, qn, qp, c, r, ckv, kpe,
+                                              layer, pos, kvl)
             h = h + L.dense(a["wo"], out)
         h, picks = ffn(h, rows)
         if picks is not None:

@@ -76,6 +76,7 @@ if TYPE_CHECKING:  # the protocol lives in the package root (no cycle)
 
 from repro.configs.base import ModelConfig
 from repro.data import tokenizer as tok
+from repro.kernels import ops as kops
 from repro.models import kvcache as KV
 from repro.models import model as M
 from repro.tracing import span
@@ -226,7 +227,8 @@ def _jit_copy(width: int):
         if fn is None:
             def copy_fn(cache, src_idx, dst_idx, length):
                 # every pool array: K and V, or the latent c_kv and k_pe
-                return {n: KV.copy_prefix(x, src_idx, dst_idx, length, width)
+                return {n: KV.copy_prefix(x, src_idx, dst_idx, length, width,
+                                          n in KV.LINES_MINOR)
                         for n, x in cache.items()}
             fn = jax.jit(copy_fn, donate_argnums=(0,))
             _COPY_JITS[width] = fn
@@ -365,6 +367,12 @@ class ServingEngine:
             self.stats.update({f"expert_picks_{i}": 0
                                for i in range(cfg.experts_held)},
                               experts_hit=0)
+        # latent cache: lines the TPU decode kernel reads (each live row's
+        # blocks up to its position), against the lines the pool holds
+        self._latent_block = (kops.latent_block_lines(max_len)
+                              if cfg.is_mla else 0)
+        if self._latent_block:
+            self.stats.update(latent_lines_read=0, latent_lines_stored=0)
 
     def _steps(self):
         """Resolve the jitted step pair against the CURRENT kernel-dispatch
@@ -726,6 +734,12 @@ class ServingEngine:
                 self.params, jnp.asarray(tokens), self.pos, self.cache,
                 self.key, jnp.asarray(temps), jnp.asarray(live))
             self.stats["decode_rows"] += self.slots
+            if self._latent_block:
+                bk = self._latent_block
+                n = np.minimum(self._pos_np[live_slots] + 1, self.max_len)
+                self.stats["latent_lines_read"] += int(
+                    np.sum(-(-n // bk) * bk))
+                self.stats["latent_lines_stored"] += self.slots * self.max_len
             self._track_retraces()
             return _DecodePass(live_slots, nxt, load[0] if load else None)
 

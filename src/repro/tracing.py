@@ -37,6 +37,14 @@ summed over decode steps and layers, over the rows that serve a request
 They cover the engine's whole life, warm-up and drain included. The
 decode program returns them with the sampled ids; the engine reads both
 in its one sync.
+
+For a latent cache it also holds ``latent_lines_read`` (summed over
+decode steps: for each row serving a request, its lines up to its
+position rounded up to whole blocks of the TPU decode kernel,
+``kernels/latent_decode_attention.py``, which reads nothing else) and
+``latent_lines_stored`` (slots x lines of the pool per decode step), per
+layer. Their ratio is the share of the pool the kernel reads. Both come
+from the engine's host mirror of the positions, with no device read.
 """
 from __future__ import annotations
 

@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.kernels import ops as kops
 from repro.models import kvcache as KV
 from repro.models import layers as L
 from repro.models import mla as MLA
@@ -176,17 +177,20 @@ def test_dropless_layer_computes_every_routed_pair(model):
 
 def test_latent_pool_decode_write_and_prefix_copy():
     """The in-place decode write lands on (layer, row, position) of both
-    latent arrays and nowhere else; the prefix copy moves a slot's first
-    lines across every layer into another slot."""
+    latent arrays and nowhere else (k_pe, stored lines-minor, through its
+    lines-major view); the prefix copy moves a slot's first lines across
+    every layer into another slot."""
     cfg = _cfg()
     cache = KV.init_latent_cache(cfg, 3, 16, jnp.float32)
+    assert cache["kpe"].shape == (cfg.n_layers, 3, cfg.qk_rope_head_dim, 16)
     ckv = jax.random.normal(jax.random.PRNGKey(0), cache["ckv"].shape)
     kpe = jax.random.normal(jax.random.PRNGKey(1), cache["kpe"].shape)
     c_new = jnp.ones((3, 1, cfg.kv_lora_rank))
     r_new = -jnp.ones((3, 1, cfg.qk_rope_head_dim))
     pos = jnp.asarray([4, 0, 9], jnp.int32)
-    a, b = KV.write_decode(ckv, kpe, c_new, r_new, (1, pos), None)
-    for x, old, v in ((a, ckv, 1.0), (b, kpe, -1.0)):
+    a, b = KV.write_decode(ckv, KV.lines_major(kpe), c_new, r_new, (1, pos),
+                           None)
+    for x, old, v in ((a, ckv, 1.0), (b, KV.lines_major(kpe), -1.0)):
         x, old = np.asarray(x), np.asarray(old).copy()
         for row, line in enumerate([4, 0, 9]):
             assert (x[1, row, line] == v).all()
@@ -197,8 +201,9 @@ def test_latent_pool_decode_write_and_prefix_copy():
     got = _jit_copy(8)({"ckv": ckv, "kpe": kpe}, jnp.asarray([2], jnp.int32),
                        jnp.asarray([0], jnp.int32),
                        jnp.asarray([5], jnp.int32))
+    want["ckv"][:, 0, :5] = want["ckv"][:, 2, :5]
+    want["kpe"][:, 0, :, :5] = want["kpe"][:, 2, :, :5]
     for name, w in want.items():
-        w[:, 0, :5] = w[:, 2, :5]
         np.testing.assert_array_equal(np.asarray(got[name]), w)
 
 
@@ -233,6 +238,34 @@ def test_engine_serves_the_stack_batched_with_prefix_reuse(model):
     assert 0 < sum(picks) <= eng.stats["steps"] * 2 * cfg.top_k
     assert 0 < eng.stats["experts_hit"] <= eng.stats["steps"] * 2
     assert "expert_picks_0" not in ServingEngine(
+        *_dense(), batch_slots=1, max_len=32).stats
+
+
+def test_engine_counts_the_latent_lines_its_decode_reads(model,
+                                                        monkeypatch):
+    """``latent_lines_read`` sums, over decode steps, each row's lines up
+    to the position it writes, rounded up to whole kernel blocks (8 lines
+    here), for the rows that serve a request only: two of four slots
+    stay parked throughout and count 0. ``latent_lines_stored`` is slots
+    x lines per step. A dense engine has neither."""
+    from repro.serving.engine import ServingEngine
+    monkeypatch.setattr(kops, "latent_block_lines", lambda m: min(8, m))
+    cfg, params = model
+    eng = ServingEngine(cfg, params, batch_slots=4, max_len=64,
+                        prefill_chunk=16)
+    reqs = [eng.submit([30 + i] * (5 + 7 * i), max_new_tokens=4 + 6 * i)
+            for i in range(2)]
+    eng.run_until_done()
+    want = 0
+    for r in reqs:
+        # decode writes the line of every output token but the last, at
+        # positions len(prompt) .. len(prompt) + outputs - 2
+        for pos in range(len(r.prompt_ids), len(r.prompt_ids)
+                         + len(r.output_ids) - 1):
+            want += -(-(pos + 1) // 8) * 8
+    assert eng.stats["latent_lines_read"] == want > 0
+    assert eng.stats["latent_lines_stored"] == eng.stats["steps"] * 4 * 64
+    assert "latent_lines_read" not in ServingEngine(
         *_dense(), batch_slots=1, max_len=32).stats
 
 

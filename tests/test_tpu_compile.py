@@ -151,3 +151,47 @@ def test_decode_step_touches_the_kv_pool_in_place(one_chip):
         re.escape(whole) + r"\S* ([\w-]+)\(", entry)}
     assert made <= {"parameter", "get-tuple-element", "tuple", "bitcast"}, \
         made
+
+
+def test_latent_decode_step_reads_the_pool_in_place(one_chip, monkeypatch):
+    """The deepseek-v2-lite decode program as a TPU process compiles it
+    (published widths, bf16, 4,096 lines): each layer's absorbed attention
+    is one Mosaic kernel that reads the layer-stacked latent pool in place
+    and writes the k_pe line. Its temporaries hold no copy of the pool, no
+    op anywhere copies or transposes a whole pool array, and the entry
+    computation makes pool-shaped buffers only as parameters, tuple parts,
+    bitcasts and layer 0's in-place line writes and kernel (the one dense
+    layer lies outside the layer loop). 8 slots: at 2 and 4 the compiler moves the
+    28-57 MB k_pe pool into the chip's fast memory and back around each
+    layer, which a served pool (32 slots, 453 MB) is too large for."""
+    monkeypatch.setattr(dispatch, "interpret_mode", lambda: False)
+    slots, max_len, bf = 8, 4096, jnp.bfloat16
+    cfg = get_config("deepseek-v2-lite").variant(n_experts_held=8)
+    params = _placed(one_chip, jax.eval_shape(
+        lambda: M.init_params(cfg, jax.random.PRNGKey(0), dtype=bf)))
+    cache = _placed(one_chip, M.init_cache_specs(cfg, slots, max_len, bf))
+    key = _placed(one_chip, jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    rows = _on(one_chip, (slots,), jnp.int32)
+    decode_step, _ = _jit_steps(cfg, max_len, False)
+    compiled = decode_step.lower(
+        params, _on(one_chip, (slots, 1), jnp.int32), rows, cache, key,
+        _on(one_chip, (slots,), jnp.float32), rows).compile()
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool / 100, (temp, pool)
+    hlo = compiled.as_text()
+    assert re.search(r"%latent_decode_attention\S* = .* custom-call\(", hlo)
+    assert 'custom_call_target="tpu_custom_call"' in hlo
+    L_, C, R = cfg.n_layers, cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    shapes = [f"bf16[{L_},{slots},{max_len},{C}]",
+              f"bf16[{L_},{slots},{R},{max_len}]",
+              f"bf16[{L_},{slots},{max_len},{R}]"]
+    entry = hlo[hlo.index("\nENTRY"):]
+    for whole in shapes:
+        ops = r"\S* ([\w-]+)\("
+        moved = {m.group(1) for m in re.finditer(re.escape(whole) + ops, hlo)}
+        assert not moved & {"copy", "copy-start", "copy-done", "transpose"}, \
+            (whole, moved)
+        made = {m.group(1) for m in re.finditer(re.escape(whole) + ops, entry)}
+        assert made <= {"parameter", "get-tuple-element", "tuple", "bitcast",
+                        "fusion", "custom-call"}, (whole, made)

@@ -36,7 +36,7 @@ from ..core import Finding, Module, RunContext, call_name, dotted_name, \
 KERNEL_IMPL_MODULES = frozenset({
     "flash_attention", "ragged_prefill_attention",
     "batched_decode_attention", "decode_attention", "chunked_gla",
-    "rmsnorm"})
+    "rmsnorm", "latent_decode_attention"})
 _ALLOWED = frozenset({"ops", "dispatch", "ref"})
 
 
